@@ -401,7 +401,9 @@ def bench_scenario_render():
     )
 
 
-def _mixed_campus_scenario(n_racks, duration, hz):
+def mixed_campus_scenario(n_racks, duration, hz):
+    """The heterogeneous acceptance campus: 4 model workloads plus the
+    inference-diurnal block, staggered starts, a mid-trace fault cascade."""
     return SC.mixed_campus(
         n_racks,
         ("llama3_2_1b", "deepseek_v3_671b", "chatglm3_6b", "whisper_large_v3"),
@@ -446,7 +448,7 @@ def bench_mixed_campus():
     n_racks = _q(1024, 64)
     duration = _q(88.0, 30.0)
     hz = 200.0
-    s = _mixed_campus_scenario(n_racks, duration, hz)
+    s = mixed_campus_scenario(n_racks, duration, hz)
     cfg = pdu.make_pdu(sample_dt=1.0 / hz)
     spec = compliance.GridSpec.create()
     run = lambda engine: fleet.condition_scenario_streaming(
@@ -494,7 +496,7 @@ def bench_mixed_campus_health():
     n_racks = _q(1024, 64)
     duration = _q(88.0, 30.0)
     hz = 200.0
-    s = _mixed_campus_scenario(n_racks, duration, hz)
+    s = mixed_campus_scenario(n_racks, duration, hz)
     cfg = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True)
     spec = compliance.GridSpec.create()
     run = lambda: fleet.condition_scenario_streaming(
@@ -561,7 +563,7 @@ def bench_mixed_campus_safemode():
     n_racks = _q(1024, 64)
     duration = _q(88.0, 30.0)
     hz = 200.0
-    s = _mixed_campus_scenario(n_racks, duration, hz)
+    s = mixed_campus_scenario(n_racks, duration, hz)
     cfg_off = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True)
     cfg_on = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True, safemode=True)
     spec = compliance.GridSpec.create()
@@ -610,27 +612,13 @@ def bench_mixed_campus_safemode():
     )
 
 
-def bench_mixed_campus_faulty():
-    """ISSUE-6 acceptance campus: the 1024-rack heterogeneous fleet under a
-    stochastic fault soup (ESS trips ~30% of units offline at the worst
-    interval, rack power losses, sensor-dropout NaN windows) plus one
-    scripted mid-trace cascade injected into the fault engine's rack
-    channel — conditioned end-to-end by the degraded-mode scanned engine,
-    with the availability mask derived in-jit from the schedule's episode
-    table.  Asserts the campus still meets the ramp spec with a third of
-    the conditioning fleet dark (the honest claim rides in
-    min_online_frac), and in ``--quick`` mode cross-checks the host-loop
-    engine for degraded-path equivalence.
-
-    The campus renders with ``edge_pad='clamp'`` — the legacy zero-padded
-    smoothing window fabricates a fleet-synchronized half-power decay at
-    the trace boundaries, which no spec-compliant campus should be judged
-    on."""
+def faulty_campus_scenario(n_racks, duration, hz):
+    """The fault-soup acceptance campus of ``bench_mixed_campus_faulty``:
+    the mixed campus with a stochastic fault schedule (about 30% of ESS
+    units offline at the worst interval, rack power losses, sensor-dropout
+    NaN windows) plus one scripted mid-trace rack cascade, attached."""
     from repro.power import faults as FLT
 
-    n_racks = _q(1024, 256)  # quick stays large enough for fleet statistics
-    duration = _q(88.0, 30.0)
-    hz = 200.0
     s = SC.mixed_campus(
         n_racks,
         ("llama3_2_1b", "deepseek_v3_671b", "chatglm3_6b", "whisper_large_v3"),
@@ -663,7 +651,30 @@ def bench_mixed_campus_faulty():
         (lo + i, t0f + i * step, min(t0f + i * step + durf, s.total_samples))
         for i in range(n_cas)
     ])
-    s = SC.attach_faults(s, sched)
+    return SC.attach_faults(s, sched)
+
+
+def bench_mixed_campus_faulty():
+    """Fault-soup acceptance campus: the 1024-rack heterogeneous fleet under a
+    stochastic fault soup (ESS trips ~30% of units offline at the worst
+    interval, rack power losses, sensor-dropout NaN windows) plus one
+    scripted mid-trace cascade injected into the fault engine's rack
+    channel — conditioned end-to-end by the degraded-mode scanned engine,
+    with the availability mask derived in-jit from the schedule's episode
+    table.  Asserts the campus still meets the ramp spec with a third of
+    the conditioning fleet dark (the honest claim rides in
+    min_online_frac), and in ``--quick`` mode cross-checks the host-loop
+    engine for degraded-path equivalence.
+
+    The campus renders with ``edge_pad='clamp'`` — the legacy zero-padded
+    smoothing window fabricates a fleet-synchronized half-power decay at
+    the trace boundaries, which no spec-compliant campus should be judged
+    on."""
+    n_racks = _q(1024, 256)  # quick stays large enough for fleet statistics
+    duration = _q(88.0, 30.0)
+    hz = 200.0
+    s = faulty_campus_scenario(n_racks, duration, hz)
+    sched = s.faults
     cfg = pdu.make_pdu(sample_dt=1.0 / hz, degraded_mode=True)
     spec = compliance.GridSpec.create()
     run = lambda engine: fleet.condition_scenario_streaming(

@@ -101,6 +101,9 @@ def main() -> None:
     # as a plain script (``python benchmarks/run.py``) from anywhere.
     sys.path.insert(0, _REPO_ROOT)
     sys.path.insert(0, os.path.join(_REPO_ROOT, "src"))
+    from repro.utils import compile_cache
+
+    compile_cache.configure()
     from benchmarks import kernel_benches, paper_benches
 
     # The tracked trajectory from the previous PR: read it BEFORE the run so

@@ -32,6 +32,14 @@ from repro.kernels import ops
 from repro.utils import pytree_dataclass, static_field
 
 
+def _mm(a, b):
+    """Matrix product at full f32 precision on every backend (the CPU's
+    default).  A TPU's default rounds f32 operands to bf16: ``K^-1 A'``
+    of the plan came out 2.5e-3 off, and the conditioned campus SoC 5.6e-6
+    off the CPU's where full precision keeps it within 6e-8."""
+    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 # --------------------------------------------------------------------------
 # Generic small-QP ADMM solver:  min 1/2 x'Px + q'x  s.t.  l <= Ax <= u
 # --------------------------------------------------------------------------
@@ -61,25 +69,25 @@ def solve_qp_admm(
     whole solve is a single XLA loop with no data-dependent control flow.
     """
     n = q.shape[0]
-    kkt = p_mat + sigma * jnp.eye(n) + rho * (a_mat.T @ a_mat)
+    kkt = p_mat + sigma * jnp.eye(n) + rho * _mm(a_mat.T, a_mat)
     chol = jax.scipy.linalg.cho_factor(kkt)
 
     def body(carry, _):
         x, z, y = carry
-        rhs = sigma * x - q + a_mat.T @ (rho * z - y)
+        rhs = sigma * x - q + _mm(a_mat.T, rho * z - y)
         x_new = jax.scipy.linalg.cho_solve(chol, rhs)
-        ax = a_mat @ x_new
+        ax = _mm(a_mat, x_new)
         z_new = jnp.clip(ax + y / rho, lo, hi)
         y_new = y + rho * (ax - z_new)
         return (x_new, z_new, y_new), None
 
     x0 = jnp.zeros_like(q)
-    z0 = jnp.clip(a_mat @ x0, lo, hi)
+    z0 = jnp.clip(_mm(a_mat, x0), lo, hi)
     y0 = jnp.zeros_like(z0)
     (x, z, y), _ = jax.lax.scan(body, (x0, z0, y0), None, length=iters)
-    ax = a_mat @ x
+    ax = _mm(a_mat, x)
     primal = jnp.max(jnp.abs(ax - jnp.clip(ax, lo, hi)))
-    dual = jnp.max(jnp.abs(p_mat @ x + q + a_mat.T @ y))
+    dual = jnp.max(jnp.abs(_mm(p_mat, x) + q + _mm(a_mat.T, y)))
     return QPSolution(x=x, primal_residual=primal, dual_residual=dual)
 
 
@@ -235,8 +243,8 @@ def _build_qp(
     # e_{k+1} = e0 + (G x)_k / ds_ref
     w = jnp.ones((h,), jnp.float32).at[h - 1].add(cfg.lam_term)  # stage + terminal
     ge = g / ds_ref
-    p_track = 2.0 * (ge.T * w) @ ge
-    q_track = 2.0 * ge.T @ (w * e0)
+    p_track = _mm(2.0 * (ge.T * w), ge)
+    q_track = _mm(2.0 * ge.T, w * e0)
 
     # Magnitude penalty lam_i * (c^2 + d^2) / imax^2.
     p_mag = 2.0 * cfg.lam_i / (cfg.i_max**2) * jnp.eye(2 * h)
@@ -244,9 +252,12 @@ def _build_qp(
     # Smoothness on u = (c - d)/imax: D u with first row including u_prev.
     diff = jnp.eye(h, dtype=jnp.float32) - jnp.eye(h, k=-1, dtype=jnp.float32)
     sel = jnp.concatenate([jnp.eye(h), -jnp.eye(h)], axis=1) / cfg.i_max  # u = S x
-    dmat = diff @ sel  # (h, 2h)
-    p_smooth = 2.0 * cfg.lam_delta * dmat.T @ dmat
-    q_smooth = -2.0 * cfg.lam_delta * dmat.T @ (jnp.eye(h, dtype=jnp.float32)[:, 0] * u_prev)
+    dmat = _mm(diff, sel)  # (h, 2h)
+    p_smooth = _mm(2.0 * cfg.lam_delta * dmat.T, dmat)
+    q_smooth = _mm(
+        -2.0 * cfg.lam_delta * dmat.T,
+        jnp.eye(h, dtype=jnp.float32)[:, 0] * u_prev,
+    )
 
     p_mat = p_track + p_mag + p_smooth
     q_vec = q_track + q_smooth
@@ -342,15 +353,15 @@ def make_plan(
 
     w = jnp.ones((h,), jnp.float32).at[h - 1].add(cfg.lam_term)
     ge = g / ds_ref
-    p_track = 2.0 * (ge.T * w) @ ge
+    p_track = _mm(2.0 * (ge.T * w), ge)
     p_mag = 2.0 * cfg.lam_i / (cfg.i_max**2) * jnp.eye(2 * h)
     diff = jnp.eye(h, dtype=jnp.float32) - jnp.eye(h, k=-1, dtype=jnp.float32)
     sel = jnp.concatenate([jnp.eye(h), -jnp.eye(h)], axis=1) / cfg.i_max
-    dmat = diff @ sel
-    p_smooth = 2.0 * cfg.lam_delta * dmat.T @ dmat
+    dmat = _mm(diff, sel)
+    p_smooth = _mm(2.0 * cfg.lam_delta * dmat.T, dmat)
     p_mat = p_track + p_mag + p_smooth
 
-    q_e0 = 2.0 * ge.T @ w  # q_track = q_e0 * e0
+    q_e0 = _mm(2.0 * ge.T, w)  # q_track = q_e0 * e0
     q_du = -2.0 * cfg.lam_delta * dmat[0]  # q_smooth = q_du * u_prev
 
     a_mat = jnp.concatenate([jnp.eye(2 * h), g], axis=0)  # (3h, 2h)
@@ -362,7 +373,7 @@ def make_plan(
     )
     soc_rows = jnp.concatenate([jnp.zeros((2 * h,)), jnp.ones((h,))])
 
-    kkt = p_mat + sigma * jnp.eye(2 * h) + rho * (a_mat.T @ a_mat)
+    kkt = p_mat + sigma * jnp.eye(2 * h) + rho * _mm(a_mat.T, a_mat)
     kkt_chol = jnp.linalg.cholesky(kkt)
     # Explicit K^-1 (tiny, SPD, well-conditioned: P is PSD + sigma I + rho
     # A'A): the ADMM x-update becomes two small GEMMs instead of a pair of
@@ -374,7 +385,7 @@ def make_plan(
         a_mat=a_mat,
         kkt_chol=kkt_chol,
         kkt_inv_sigma=sigma * kkt_inv,
-        kkt_inv_at=kkt_inv @ a_mat.T,
+        kkt_inv_at=_mm(kkt_inv, a_mat.T),
         kkt_inv=kkt_inv,
         q_e0=q_e0,
         q_du=q_du,
@@ -433,11 +444,11 @@ def solve_qp_admm_plan(
     a_mat = plan.a_mat
     if warm is None:
         x0 = jnp.zeros_like(q)
-        z0 = jnp.clip(a_mat @ x0, lo, hi)
+        z0 = jnp.clip(_mm(a_mat, x0), lo, hi)
         y0 = jnp.zeros_like(z0)
     else:
         x0, z0, y0 = warm.x, warm.z, warm.y
-    kq = plan.kkt_inv @ q  # state-only: constant across iterations
+    kq = _mm(plan.kkt_inv, q)  # state-only: constant across iterations
 
     # Fused iteration loop (ops.admm_iterate): the stacked x-update GEMM
     # and the structure-exploiting A x (A = [I; G]) — one Pallas kernel on
@@ -448,9 +459,9 @@ def solve_qp_admm_plan(
         kkt_stack, a_mat[2 * plan.horizon :], kq, lo, hi, x0, z0, y0,
         rho=rho, iters=iters,
     )
-    ax = a_mat @ x
+    ax = _mm(a_mat, x)
     primal = jnp.max(jnp.abs(ax - jnp.clip(ax, lo, hi)), axis=0)
-    dual = jnp.max(jnp.abs(plan.p_mat @ x + q + a_mat.T @ y), axis=0)
+    dual = jnp.max(jnp.abs(_mm(plan.p_mat, x) + q + _mm(a_mat.T, y)), axis=0)
     return (
         QPSolution(x=x, primal_residual=primal, dual_residual=dual),
         QPWarmState(x=x, z=z, y=y),

@@ -131,9 +131,13 @@ def make_discrete_filter(p: LCFilterParams, dt: float) -> DiscreteFilter:
 
 
 def steady_state(filt: DiscreteFilter, u: jax.Array) -> jax.Array:
-    """State for a constant input u (solves (I - Ad) x = Bd u)."""
+    """State for a constant input u (solves (I - Ad) x = Bd u).
+
+    ``Bd u`` at full f32 precision: a TPU's default would round u to bf16,
+    and the conditioned trace would start ~1e-3 pu off its steady state."""
     n = filt.ad.shape[0]
-    return jnp.linalg.solve(jnp.eye(n) - filt.ad, filt.bd @ u)
+    bu = jnp.matmul(filt.bd, u, precision=jax.lax.Precision.HIGHEST)
+    return jnp.linalg.solve(jnp.eye(n) - filt.ad, bu)
 
 
 def simulate(

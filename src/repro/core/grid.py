@@ -17,10 +17,9 @@ module scales the scanned conditioner from one campus to a region:
   engines are bitwise identical on the POI aggregates (the parity suite
   pins this on a forced 8-device CPU mesh).  The rack axis stays whole
   per campus: per-rack ``psum`` reassociates the campus mean and breaks
-  bitwise parity (EXPERIMENTS §Grid-region), and on jax 0.4.x mixing
-  ``shard_map`` auto axes with in-body sharding constraints aborts the
-  process outright — so the "data" axis is reserved for the GSPMD
-  ``shard_racks`` paths and left unmentioned (replicated) here.
+  bitwise parity (EXPERIMENTS §Grid-region), so the "data" axis is
+  reserved for the GSPMD ``shard_racks`` paths and left unmentioned
+  (replicated) here.
 * ``poi_response`` — first-order grid coupling: a swing-equation style
   frequency-deviation sensitivity and a proportional voltage-deviation
   estimate at the POI.
@@ -580,8 +579,7 @@ def _region_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, bank, mbank):
     ``psum`` over the "campus" axis (bitwise equal to the left-to-right
     sequential sum — one campus per shard).  Everything is *manual* over
     the campus axis and replicated over the rest of the mesh: no auto
-    axes, no in-body sharding constraints (jax 0.4.x aborts the process
-    on that combination — see ``rules.shard_map_compat``)."""
+    axes and no in-body sharding constraints (``rules.shard_map_compat``)."""
     caxis = "campus"
 
     def build():

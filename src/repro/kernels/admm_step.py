@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _admm_kernel(
     ks_ref, g_ref, kq_ref, lo_ref, hi_ref, x0_ref, z0_ref, y0_ref,
@@ -39,14 +37,18 @@ def _admm_kernel(
     lo = lo_ref[...]
     hi = hi_ref[...]
 
+    # f32 products at full precision: the MXU's default pass count is a
+    # backend choice, and the reference pins HIGHEST too.
+    dot = functools.partial(
+        jnp.dot, preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
     def body(_, carry):
         x, z, y = carry
         rhs = jnp.concatenate([x, rho * z - y], axis=0)  # (5h, r)
-        x_new = jnp.dot(ks, rhs, preferred_element_type=jnp.float32) - kq
-        ax = jnp.concatenate(
-            [x_new, jnp.dot(g, x_new, preferred_element_type=jnp.float32)],
-            axis=0,
-        )
+        x_new = dot(ks, rhs) - kq
+        ax = jnp.concatenate([x_new, dot(g, x_new)], axis=0)
         # y / rho, not y * (1/rho): the reciprocal multiply is a different
         # rounding and ADMM clip boundaries amplify the ulp over the loop.
         z_new = jnp.clip(ax + y / rho, lo, hi)
@@ -113,7 +115,7 @@ def admm_iterate(
             jax.ShapeDtypeStruct((n3, r + r_pad), f32),
             jax.ShapeDtypeStruct((n3, r + r_pad), f32),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(kkt_stack.astype(f32), g_blk.astype(f32), *batched)
     return x[:, :r], z[:, :r], y[:, :r]

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _lc_kernel(
     ad_ref, bd_ref, x0_ref, u_ref, c_ref, y_ref, xf_ref, state,
@@ -97,7 +95,7 @@ def lc_filter(
             jax.ShapeDtypeStruct((3, r), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((3, r), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,

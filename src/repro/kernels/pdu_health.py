@@ -52,8 +52,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _megakernel(
     *refs,
@@ -370,7 +368,7 @@ def pdu_health_sim(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
     grid_t, soc_t, sf = outs[0][:t, :r], outs[1][:t, :r], outs[2][:, :r]

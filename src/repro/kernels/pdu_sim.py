@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _pdu_kernel(
     *refs,
@@ -198,7 +196,7 @@ def pdu_sim(
             jax.ShapeDtypeStruct((5, r), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((5, r), jnp.float32)],
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(*operands)
     g_f, soc_f, x_f = sf[0], sf[1], sf[2:5].T

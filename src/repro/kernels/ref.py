@@ -6,6 +6,8 @@ wrappers fall back to on non-TPU backends.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -384,11 +386,14 @@ def admm_iterate(
     equivalence tests bound this against the build-per-step oracle.
     """
     rho = jnp.float32(rho)
+    # Full f32 products on every backend (a TPU's default is fewer MXU
+    # passes); on the CPU this is the default anyway.
+    dot = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 
     def body(carry, _):
         x, z, y = carry
-        x_new = kkt_stack @ jnp.concatenate([x, rho * z - y], axis=0) - kq
-        ax = jnp.concatenate([x_new, g_blk @ x_new], axis=0)
+        x_new = dot(kkt_stack, jnp.concatenate([x, rho * z - y], axis=0)) - kq
+        ax = jnp.concatenate([x_new, dot(g_blk, x_new)], axis=0)
         z_new = jnp.clip(ax + y / rho, lo, hi)
         y_new = y + rho * (ax - z_new)
         return (x_new, z_new, y_new), None

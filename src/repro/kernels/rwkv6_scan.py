@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 
 def _rwkv6_kernel(
     r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sf_ref, state,
@@ -101,7 +99,7 @@ def rwkv6_scan(
             jax.ShapeDtypeStruct((b * h, d, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

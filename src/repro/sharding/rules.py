@@ -18,56 +18,25 @@ distributed) with a note collected for the dry-run report.
 """
 from __future__ import annotations
 
-import enum
-import inspect
 import re
 from typing import Any
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-# ----------------------------------------------------------- version compat --
-# jax added ``jax.sharding.AxisType`` (and the ``axis_types=`` kwarg of
-# ``jax.make_mesh``) well after 0.4.x; this repo targets both sides of that
-# drift.  All mesh construction goes through ``make_mesh`` below, which
-# forwards ``axis_types`` only when the installed jax understands it.
-
-try:  # jax >= 0.5.x
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-except ImportError:  # pragma: no cover - depends on the installed jax
-
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Fallback for ``jax.sharding.AxisType`` on older jax: carries the
-        same member names so call sites are version-agnostic; the value is
-        simply dropped by ``make_mesh`` (old jax treats every axis as Auto)."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-_JAX_MAKE_MESH = getattr(jax, "make_mesh", None)
-_MAKE_MESH_TAKES_AXIS_TYPES = _JAX_MAKE_MESH is not None and (
-    "axis_types" in inspect.signature(_JAX_MAKE_MESH).parameters
-)
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None) -> Mesh:
-    """``jax.make_mesh`` across the ``axis_types`` API drift.
-
-    Also covers jax releases predating ``jax.make_mesh`` itself by falling
-    back to a plain ``Mesh`` over a reshaped device array."""
-    if _JAX_MAKE_MESH is None:  # pragma: no cover - depends on installed jax
-        devs = np.asarray(devices if devices is not None else jax.devices())
-        return Mesh(devs.reshape(tuple(axis_shapes)), tuple(axis_names))
-    kw: dict[str, Any] = {}
-    if devices is not None:
-        kw["devices"] = devices
-    if axis_types is not None and _MAKE_MESH_TAKES_AXIS_TYPES:
-        kw["axis_types"] = tuple(axis_types)
-    return _JAX_MAKE_MESH(tuple(axis_shapes), tuple(axis_names), **kw)
+    """``jax.make_mesh`` with every axis ``Auto`` unless ``axis_types`` says
+    otherwise.  The installed jax defaults to ``Explicit`` axes, under which
+    plain indexing of a sharded result (``x[c]`` on a campus-sharded array)
+    raises; every mesh in this repo is written for GSPMD's ``Auto`` axes."""
+    if axis_types is None:
+        axis_types = (AxisType.Auto,) * len(axis_names)
+    kw: dict[str, Any] = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(
+        tuple(axis_shapes), tuple(axis_names), axis_types=tuple(axis_types), **kw
+    )
 
 
 # role -> (axis assignment per tensor dim, counted from the LAST dim)
@@ -274,26 +243,17 @@ def shard_racks_in_jit(
 
 # --------------------------------------------------------------- shard_map --
 
-try:  # jax >= 0.6 exposes it at the top level
-    _shard_map = jax.shard_map  # type: ignore[attr-defined]
-except AttributeError:  # pragma: no cover - depends on the installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
+def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check.
 
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs, *, check_rep=False):
-    """``shard_map`` across the export-location API drift.
-
-    ``check_rep=False`` is the repo default: the grid-region engine returns
-    ``psum``-reduced POI aggregates under ``out_specs=P()`` — genuinely
-    replicated, but the 0.4.x replication checker cannot prove it through
-    ``lax.scan`` carries.  Do NOT pass ``auto=`` axes or call
-    ``with_sharding_constraint`` inside the mapped body: on jax 0.4.x that
-    combination aborts the *process* inside XLA's SPMD partitioner
-    (``Check failed: sharding.IsManualSubgroup()``) — it is not a catchable
-    error, so there is no runtime fallback (EXPERIMENTS §Grid-region).
+    The grid-region engine returns ``psum``-reduced POI aggregates under
+    ``out_specs=P()``: they are replicated, but the checker cannot prove it
+    through ``lax.scan`` carries.  The mapped body names no auto axes and
+    calls no ``with_sharding_constraint``: every axis is manual over the
+    campus shards (EXPERIMENTS §Grid-region).
     """
-    return _shard_map(
-        f, mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_rep
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
