@@ -26,59 +26,9 @@ QUICK = os.environ.get("REPRO_BENCH_QUICK", "") == "1"
 # string.
 UNITS: dict[str, dict] = {}
 
-# Campus workloads registered by the bench functions as they run, for the
-# ``--profile`` pass: {bench_name: {"cfg", "scenario", "spec",
-# "chunk_intervals", "qp_iters"}}.  run.py re-runs each through the HOST
-# engine (the one whose render/solve/assemble stages are host-visible) with
-# ``core.profiling`` spans enabled and prints the phase breakdown.
-PROFILES: dict[str, dict] = {}
-
 
 def _q(full, quick):
     return quick if QUICK else full
-
-
-def profile_kernel_estimate(w: dict) -> float:
-    """Estimated seconds the hardware megakernel contributes to one run of
-    the registered workload: one controller interval timed standalone
-    (jitted, same backend dispatch the engines use) scaled by the interval
-    count.  The in-engine solve phase fuses QP solve + kernel into one
-    program, so this standalone estimate is how ``--profile`` splits them.
-    """
-    cfg, s = w["cfg"], w["scenario"]
-    hz = float(s.sample_hz)
-    k = max(int(round(float(cfg.controller.dt) * hz)), 1)
-    chunk = jax.jit(lambda: SC.render(s, 0, k))()
-    if chunk.ndim == 1:
-        chunk = chunk[:, None]
-    # Kernel-only timing: the engines bridge sensor-dropout NaN before the
-    # kernel sees the block, so feed it finite samples.
-    chunk = jnp.nan_to_num(chunk, nan=0.0)
-    st = pdu.init_state(cfg, chunk[0])
-    ep = cfg.ess_params
-    filt = st.filter_obj
-    kkw = dict(
-        beta=float(ep.beta), dt=1.0 / hz, q_max=float(ep.q_max),
-        eta_c=float(ep.eta_c), eta_d=float(ep.eta_d),
-        p_max=float(ep.p_max), soc_min=float(ep.soc_safe_min),
-        soc_max=float(ep.soc_safe_max),
-    )
-    hin = None
-    if getattr(cfg, "track_health", False):
-        from repro.core import health as _h
-
-        hin = (_h.step_consts(cfg.health), tuple(st.health))
-    from repro.kernels import ops as _ops
-
-    run = jax.jit(lambda c: _ops.pdu_health_sim(
-        c, st.ess_state.g_filter, st.ess_state.soc, st.filter_state,
-        filt.ad, filt.bd, filt.c[0], health=hin, **kkw,
-    ))
-    jax.block_until_ready(run(chunk))  # compile
-    t0 = time.perf_counter()
-    jax.block_until_ready(run(chunk))
-    per_interval = time.perf_counter() - t0
-    return per_interval * (-(-int(s.total_samples) // k))
 
 
 def _timeit(fn, *args, n=3):
@@ -505,9 +455,6 @@ def bench_mixed_campus_health():
     run()  # compile
     us, res = _best_of(run, lambda r: r.campus_grid)
     UNITS["mixed_campus_health"] = dict(racks=n_racks, samples=s.total_samples * n_racks)
-    PROFILES["mixed_campus_health"] = dict(
-        cfg=cfg, scenario=s, spec=spec, chunk_intervals=4, qp_iters=30
-    )
 
     if QUICK:
         # Megakernel-vs-ref agreement ride-along: one controller interval of
@@ -589,9 +536,6 @@ def bench_mixed_campus_safemode():
         us, res = min(us, (time.perf_counter() - t0) * 1e6), r
     UNITS["mixed_campus_safemode"] = dict(
         racks=n_racks, samples=s.total_samples * n_racks
-    )
-    PROFILES["mixed_campus_safemode"] = dict(
-        cfg=cfg_on, scenario=s, spec=spec, chunk_intervals=4, qp_iters=30
     )
     LAST_US["mixed_campus_safemode"] = us
 
@@ -683,9 +627,6 @@ def bench_mixed_campus_faulty():
     run("scanned")  # compile
     us, res = _best_of(lambda: run("scanned"), lambda r: r.campus_grid)
     UNITS["mixed_campus_faulty"] = dict(racks=n_racks, samples=s.total_samples * n_racks)
-    PROFILES["mixed_campus_faulty"] = dict(
-        cfg=cfg, scenario=s, spec=spec, chunk_intervals=4, qp_iters=30
-    )
 
     if QUICK:
         host = run("host")

@@ -13,11 +13,6 @@ baseline entries (quick workloads are smaller, so their timings live in a
 separate namespace); seed them once with ``--quick --update-baseline``.
 ``python benchmarks/run.py --quick --gate`` is then a one-command CI smoke:
 correctness asserts (engine agreement) + perf regression gate.
-
-``--profile`` appends a per-bench phase breakdown (render / solve /
-kernel / host-sync) for the registered campus workloads, via the
-``core.profiling`` spans in the host engine — so a perf PR can see where
-the time goes before guessing.
 """
 from __future__ import annotations
 
@@ -81,13 +76,6 @@ def main() -> None:
         "record under 'quick:'-prefixed keys; the default full run writes "
         "anyway unless --gate is set)",
     )
-    ap.add_argument(
-        "--profile",
-        action="store_true",
-        help="after the delta table, re-run each campus bench workload "
-        "through the host engine with core.profiling phase spans enabled "
-        "and print a render/solve/kernel/host-sync breakdown",
-    )
     args = ap.parse_args()
     # A pre-set env var also selects quick sizes (they bind when the bench
     # modules import), so treat it exactly like --quick — otherwise quick
@@ -142,43 +130,6 @@ def main() -> None:
             upr = f"{us / units['racks']:.0f}" if units.get("racks") else "-"
             sps = f"{units['samples'] / (us / 1e6):.2e}" if units.get("samples") else "-"
             print(f"# {name},{prev_s},{us:.0f},{speedup},{upr},{sps}")
-
-    # Per-bench phase breakdown: each registered campus workload re-runs
-    # through the HOST engine (the one whose render / solve / assemble
-    # stages are host-visible) with ``core.profiling`` spans enabled.  The
-    # solve phase fuses the controller QP and the hardware megakernel into
-    # one program, so the kernel share is estimated from one standalone
-    # interval (``profile_kernel_estimate``) — printed as ``kernel_est``
-    # and NOT subtracted from ``solve``.  Phases are serialized by the
-    # profiler (dispatches block inside their span), so the profiled total
-    # sits slightly above the bench's async wall clock.
-    if args.profile:
-        from repro.core import fleet, profiling
-
-        print("\n# per-bench phase breakdown (host-engine re-run, us)")
-        print("# name,render,solve,kernel_est,host_sync,total")
-        for name, w in paper_benches.PROFILES.items():
-            run = lambda: fleet.condition(
-                w["scenario"], w["cfg"], w["spec"], engine="host",
-                stream=fleet.StreamOptions(
-                    chunk_intervals=w["chunk_intervals"]),
-                qp_iters=w["qp_iters"],
-            )
-            run()  # compile outside the spans
-            profiling.enable()
-            try:
-                run()
-                ph = profiling.phases()
-            finally:
-                profiling.disable()
-            kern = paper_benches.profile_kernel_estimate(w)
-            total = sum(ph.values())
-            print(
-                f"# {name},{ph.get('render', 0.0) * 1e6:.0f},"
-                f"{ph.get('solve', 0.0) * 1e6:.0f},{kern * 1e6:.0f},"
-                f"{ph.get('host-sync', 0.0) * 1e6:.0f},{total * 1e6:.0f}"
-            )
-            sys.stdout.flush()
 
     # Baseline writes.  A gated run never rewrites its own reference unless
     # explicitly asked; quick entries live under "quick:" so full-run

@@ -283,12 +283,13 @@ def _observers_init(bank: compliance.SpectrumBank) -> _Observers:
 def _observers_update(
     obs: _Observers, bank: compliance.SpectrumBank, ch: pdu.CampusChunk, dt: float
 ) -> _Observers:
-    return _Observers(
-        ramp_rack=compliance.ramp_observer_update(obs.ramp_rack, ch.campus_rack, dt),
-        ramp_grid=compliance.ramp_observer_update(obs.ramp_grid, ch.campus_grid, dt),
-        spec_rack=compliance.spectrum_observer_update(bank, obs.spec_rack, ch.campus_rack),
-        spec_grid=compliance.spectrum_observer_update(bank, obs.spec_grid, ch.campus_grid),
-    )
+    with _prof.scope("observers"):
+        return _Observers(
+            ramp_rack=compliance.ramp_observer_update(obs.ramp_rack, ch.campus_rack, dt),
+            ramp_grid=compliance.ramp_observer_update(obs.ramp_grid, ch.campus_grid, dt),
+            spec_rack=compliance.spectrum_observer_update(bank, obs.spec_rack, ch.campus_rack),
+            spec_grid=compliance.spectrum_observer_update(bank, obs.spec_grid, ch.campus_grid),
+        )
 
 
 def _make_bank(
@@ -442,36 +443,43 @@ def _host_stream_step(cfg, qp_iters, chunk, n_int, mesh, rack_axis, bank,
 
 def _finish_streaming(
     cfg, grid_spec, state, campus_rack, campus_grid, soc_mean, worst,
-    bank, obs, health_trace, ess_frac=None, sm_trace=None,
+    bank, obs, health_trace, ess_frac=None, sm_trace=None, *,
+    t_total=None, n_ctrl=None,
 ):
     """Assemble the result from streaming state: the compliance reports
     come from the cross-chunk observers (exact ramp, Goertzel spec lines),
     not from re-analyzing the materialized campus arrays — the arrays are
     returned for plotting/diagnostics but no longer gate compliance.  The
     observers (and their bank/spec) ride along so ``.report()`` can
-    re-derive reports later."""
-    return ConditioningResult(
-        campus_rack=campus_rack,
-        campus_grid=campus_grid,
-        soc_mean=soc_mean,
-        report_rack=compliance.report_from_observers(
-            grid_spec, obs.ramp_rack, bank, obs.spec_rack
-        ),
-        report_grid=compliance.report_from_observers(
-            grid_spec, obs.ramp_grid, bank, obs.spec_grid
-        ),
-        state=state,
-        max_qp_residual=worst,
-        health_trace=health_trace,
-        health=hlt.report(
-            _health_params(cfg), cfg.ess_params, state.health, cfg.sample_dt
-        ),
-        ess_online_frac=ess_frac,
-        safemode_trace=sm_trace,
-        grid_spec=grid_spec,
-        bank=bank,
-        observers=obs,
-    )
+    re-derive reports later.  ``t_total`` / ``n_ctrl`` cut the engine's
+    padded sample / interval outputs to the stream's length."""
+    with _prof.span("finish"):
+        if t_total is not None:
+            campus_rack, campus_grid = campus_rack[:t_total], campus_grid[:t_total]
+        if n_ctrl is not None:
+            soc_mean, ess_frac = soc_mean[:n_ctrl], ess_frac[:n_ctrl]
+        return ConditioningResult(
+            campus_rack=campus_rack,
+            campus_grid=campus_grid,
+            soc_mean=soc_mean,
+            report_rack=compliance.report_from_observers(
+                grid_spec, obs.ramp_rack, bank, obs.spec_rack
+            ),
+            report_grid=compliance.report_from_observers(
+                grid_spec, obs.ramp_grid, bank, obs.spec_grid
+            ),
+            state=state,
+            max_qp_residual=worst,
+            health_trace=health_trace,
+            health=hlt.report(
+                _health_params(cfg), cfg.ess_params, state.health, cfg.sample_dt
+            ),
+            ess_online_frac=ess_frac,
+            safemode_trace=sm_trace,
+            grid_spec=grid_spec,
+            bank=bank,
+            observers=obs,
+        )
 
 
 def _condition_fleet_streaming_impl(
@@ -596,39 +604,31 @@ def _condition_fleet_streaming_impl(
         # max_qp_residual never see whole pad intervals and stay
         # chunk-size invariant (and scanned-engine identical).
         n = min(chunk, t_total - t0)
-        with _prof.span("render") as sync:
-            tr = sync(provider(t0, n))
+        tr = provider(t0, n)
         if mesh is not None and not isinstance(tr, jax.Array):
             tr = shard_racks(tr, mesh, rack_axis)  # host-resident input
-        with _prof.span("solve") as sync:
-            if cfg.degraded_mode and faults is not None:
-                state, acc = step(
-                    state, acc, tr, jnp.asarray(c_idx, jnp.int32), faults
-                )
-            elif cfg.degraded_mode:
-                if ess_online is None or ess_online.ndim < 2:
-                    on = ess_online  # one mask (or None) for the whole stream
-                else:
-                    on = ess_online[c_idx * n_int : c_idx * n_int + -(-n // k)]
-                # The hardware weight is per *sample*: it slices by samples.
-                wt = None if ess_weight is None else ess_weight[t0 : t0 + n]
-                state, acc = step(
-                    state, acc, tr, jnp.asarray(c_idx, jnp.int32), on, wt
-                )
+        if cfg.degraded_mode and faults is not None:
+            state, acc = step(
+                state, acc, tr, jnp.asarray(c_idx, jnp.int32), faults
+            )
+        elif cfg.degraded_mode:
+            if ess_online is None or ess_online.ndim < 2:
+                on = ess_online  # one mask (or None) for the whole stream
             else:
-                state, acc = step(state, acc, tr, jnp.asarray(c_idx, jnp.int32))
-            sync(acc.worst)
+                on = ess_online[c_idx * n_int : c_idx * n_int + -(-n // k)]
+            # The hardware weight is per *sample*: it slices by samples.
+            wt = None if ess_weight is None else ess_weight[t0 : t0 + n]
+            state, acc = step(
+                state, acc, tr, jnp.asarray(c_idx, jnp.int32), on, wt
+            )
+        else:
+            state, acc = step(state, acc, tr, jnp.asarray(c_idx, jnp.int32))
 
-    with _prof.span("host-sync") as sync:
-        res = _finish_streaming(
-            cfg, grid_spec, state,
-            acc.campus_rack[:t_total], acc.campus_grid[:t_total],
-            acc.soc_mean[:n_ctrl], acc.worst,
-            bank, acc.obs, acc.health_trace, acc.ess_frac[:n_ctrl],
-            acc.sm_trace,
-        )
-        sync((res.campus_grid, res.report_grid))
-    return res
+    return _finish_streaming(
+        cfg, grid_spec, state, acc.campus_rack, acc.campus_grid,
+        acc.soc_mean, acc.worst, bank, acc.obs, acc.health_trace,
+        acc.ess_frac, acc.sm_trace, t_total=t_total, n_ctrl=n_ctrl,
+    )
 
 
 def _condition_chunk(cfg, scen, st, t0, n, *, k, qp_iters, prep=None):
@@ -652,11 +652,12 @@ def _condition_chunk(cfg, scen, st, t0, n, *, k, qp_iters, prep=None):
     # Trace-time structural check: the caller's jit retraces automatically
     # when the scenario gains/loses a fault schedule (treedef change).
     faulty = cfg.degraded_mode and scen.faults is not None
-    tr = SC.render(scen, t0, n)
-    if tr.ndim == 1:  # unbatched scenario: lift to a 1-rack fleet
-        tr = tr[:, None]
-    if prep is not None:
-        tr = prep(tr)
+    with _prof.scope("render"):
+        tr = SC.render(scen, t0, n)
+        if tr.ndim == 1:  # unbatched scenario: lift to a 1-rack fleet
+            tr = tr[:, None]
+        if prep is not None:
+            tr = prep(tr)
     return pdu.condition_campus(
         cfg, st, tr, qp_iters=qp_iters,
         faults=scen.faults if faulty else None,
@@ -795,55 +796,55 @@ def _condition_scenario_scanned_impl(
     copied before the (donated) engine consumes it, so the same checkpoint
     can seed several continuations.
     """
-    from repro.power import scenario as SC
+    with _prof.span("prepare"):
+        from repro.power import scenario as SC
 
-    _check_scenario_rate(scenario, cfg)
-    _check_scenario_faults(scenario, cfg)
-    k = max(int(round(float(cfg.controller.dt) / cfg.sample_dt)), 1)
-    chunk = max(int(chunk_intervals), 1) * k
-    start = int(start_sample)
-    stop = scenario.total_samples if stop_sample is None else int(stop_sample)
-    if not 0 <= stop <= scenario.total_samples:
-        raise ValueError(
-            f"stop_sample {stop} outside the scenario "
-            f"({scenario.total_samples} samples)"
-        )
-    if start < 0 or start % k:
-        raise ValueError(
-            f"start_sample {start} must be a non-negative multiple of the "
-            f"controller interval ({k} samples) so the resumed state stays "
-            "interval-aligned"
-        )
-    t_total = stop - start
-    if t_total <= 0:
-        raise ValueError(
-            f"start_sample {start} is past the scenario end "
-            f"(stop at {stop} samples)"
-        )
-    n_full, rem = divmod(t_total, chunk)
-    n_ctrl = -(-t_total // k)
+        _check_scenario_rate(scenario, cfg)
+        _check_scenario_faults(scenario, cfg)
+        k = max(int(round(float(cfg.controller.dt) / cfg.sample_dt)), 1)
+        chunk = max(int(chunk_intervals), 1) * k
+        start = int(start_sample)
+        stop = scenario.total_samples if stop_sample is None else int(stop_sample)
+        if not 0 <= stop <= scenario.total_samples:
+            raise ValueError(
+                f"stop_sample {stop} outside the scenario "
+                f"({scenario.total_samples} samples)"
+            )
+        if start < 0 or start % k:
+            raise ValueError(
+                f"start_sample {start} must be a non-negative multiple of the "
+                f"controller interval ({k} samples) so the resumed state stays "
+                "interval-aligned"
+            )
+        t_total = stop - start
+        if t_total <= 0:
+            raise ValueError(
+                f"start_sample {start} is past the scenario end "
+                f"(stop at {stop} samples)"
+            )
+        n_full, rem = divmod(t_total, chunk)
+        n_ctrl = -(-t_total // k)
 
-    if state is None:
-        r0 = SC.render(scenario, start, 1)[0]
-        if r0.ndim == 0:
-            r0 = r0[None]  # unbatched scenario: the engine lifts to 1 rack
-        state = pdu.init_state(cfg, r0, soc0=soc0)
-    else:
-        # The engine donates its state argument; copy so the caller's
-        # checkpoint survives (and can seed several continuations).
-        state = jax.tree_util.tree_map(jnp.copy, state)
+        if state is None:
+            r0 = SC.render(scenario, start, 1)[0]
+            if r0.ndim == 0:
+                r0 = r0[None]  # unbatched scenario: the engine lifts to 1 rack
+            state = pdu.init_state(cfg, r0, soc0=soc0)
+        else:
+            # The engine donates its state argument; copy so the caller's
+            # checkpoint survives (and can seed several continuations).
+            state = jax.tree_util.tree_map(jnp.copy, state)
 
-    bank = _make_bank(grid_spec, cfg, t_total)
-    run = _scanned_engine(
-        cfg, qp_iters, chunk, k, n_full, rem, mesh, rack_axis, bank
-    )
-    state_f, ch, obs = run(scenario, state, jnp.asarray(start, jnp.int32))
+        bank = _make_bank(grid_spec, cfg, t_total)
+        run = _scanned_engine(
+            cfg, qp_iters, chunk, k, n_full, rem, mesh, rack_axis, bank
+        )
+    with _prof.span("engine"):
+        state_f, ch, obs = run(scenario, state, jnp.asarray(start, jnp.int32))
     return _finish_streaming(
-        cfg, grid_spec, state_f,
-        ch.campus_rack[:t_total], ch.campus_grid[:t_total],
-        ch.soc_mean[:n_ctrl], ch.max_qp_residual,
-        bank, obs, ch.health, ch.ess_online_frac[:n_ctrl],
-        ch.safemode,
+        cfg, grid_spec, state_f, ch.campus_rack, ch.campus_grid,
+        ch.soc_mean, ch.max_qp_residual, bank, obs, ch.health,
+        ch.ess_online_frac, ch.safemode, t_total=t_total, n_ctrl=n_ctrl,
     )
 
 
@@ -1014,83 +1015,84 @@ def condition(
     ``condition_scenario_scanned``, ``condition_scenario_streaming``)
     remain as thin deprecated wrappers over this function.
     """
-    spec = compliance.GridSpec.create() if grid_spec is None else grid_spec
-    so = _as_stream_options(stream)
+    with _prof.span("condition"):
+        spec = compliance.GridSpec.create() if grid_spec is None else grid_spec
+        so = _as_stream_options(stream)
 
-    if hasattr(target, "campuses"):  # GridRegion (duck-typed; grid imports us)
-        from repro.core import grid as _grid
+        if hasattr(target, "campuses"):  # GridRegion (duck-typed; grid imports us)
+            from repro.core import grid as _grid
 
-        if engine != "scanned":
-            raise ValueError(
-                f"grid regions run the scanned engine only (got {engine!r})")
-        _reject_stream_options(so, "grid-region", "total_samples")
-        return _grid.condition_region(
-            cfg, target, spec, mesh=mesh,
-            chunk_intervals=so.chunk_intervals, states=so.state,
-            start_sample=so.start_sample, stop_sample=so.stop_sample,
-            **kwargs,
-        )
-
-    is_scenario = hasattr(target, "total_samples") and not callable(target)
-    if is_scenario:
-        if engine == "scanned":
-            _reject_stream_options(so, "scanned", "total_samples")
-            return _condition_scenario_scanned_impl(
-                cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
-                chunk_intervals=so.chunk_intervals, state=so.state,
+            if engine != "scanned":
+                raise ValueError(
+                    f"grid regions run the scanned engine only (got {engine!r})")
+            _reject_stream_options(so, "grid-region", "total_samples")
+            return _grid.condition_region(
+                cfg, target, spec, mesh=mesh,
+                chunk_intervals=so.chunk_intervals, states=so.state,
                 start_sample=so.start_sample, stop_sample=so.stop_sample,
                 **kwargs,
             )
-        if engine == "host":
-            _reject_stream_options(
-                so, "host", "start_sample", "stop_sample", "total_samples")
-            return _condition_scenario_host_impl(
-                cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
-                chunk_intervals=so.chunk_intervals, state=so.state,
-                **kwargs,
-            )
-        if engine == "oneshot":
-            from repro.power import scenario as SC
 
+        is_scenario = hasattr(target, "total_samples") and not callable(target)
+        if is_scenario:
+            if engine == "scanned":
+                _reject_stream_options(so, "scanned", "total_samples")
+                return _condition_scenario_scanned_impl(
+                    cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
+                    chunk_intervals=so.chunk_intervals, state=so.state,
+                    start_sample=so.start_sample, stop_sample=so.stop_sample,
+                    **kwargs,
+                )
+            if engine == "host":
+                _reject_stream_options(
+                    so, "host", "start_sample", "stop_sample", "total_samples")
+                return _condition_scenario_host_impl(
+                    cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
+                    chunk_intervals=so.chunk_intervals, state=so.state,
+                    **kwargs,
+                )
+            if engine == "oneshot":
+                from repro.power import scenario as SC
+
+                _reject_stream_options(
+                    so, "oneshot", "state", "start_sample", "stop_sample",
+                    "total_samples")
+                _check_scenario_rate(target, cfg)
+                _check_scenario_faults(target, cfg)
+                for key, val in _scenario_fault_data(cfg, target).items():
+                    kwargs.setdefault(key, val)
+                tr = SC.render(target, 0, target.total_samples)
+                if tr.ndim == 1:
+                    tr = tr[:, None]
+                return _condition_fleet_impl(cfg, tr, spec, **kwargs)
+            raise ValueError(
+                f"unknown engine {engine!r} "
+                "(expected 'scanned', 'host' or 'oneshot')")
+
+        # Raw (T, R) array or chunk provider.
+        if engine == "oneshot":
+            if callable(target):
+                raise ValueError(
+                    "engine='oneshot' needs a materialized (T, R) array "
+                    "(chunk providers stream via engine='host')")
             _reject_stream_options(
                 so, "oneshot", "state", "start_sample", "stop_sample",
                 "total_samples")
-            _check_scenario_rate(target, cfg)
-            _check_scenario_faults(target, cfg)
-            for key, val in _scenario_fault_data(cfg, target).items():
-                kwargs.setdefault(key, val)
-            tr = SC.render(target, 0, target.total_samples)
-            if tr.ndim == 1:
-                tr = tr[:, None]
-            return _condition_fleet_impl(cfg, tr, spec, **kwargs)
-        raise ValueError(
-            f"unknown engine {engine!r} "
-            "(expected 'scanned', 'host' or 'oneshot')")
-
-    # Raw (T, R) array or chunk provider.
-    if engine == "oneshot":
-        if callable(target):
+            return _condition_fleet_impl(cfg, target, spec, **kwargs)
+        if engine == "host":
+            _reject_stream_options(so, "host", "start_sample", "stop_sample")
+            return _condition_fleet_streaming_impl(
+                cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
+                chunk_intervals=so.chunk_intervals, state=so.state,
+                total_samples=so.total_samples, **kwargs,
+            )
+        if engine == "scanned":
             raise ValueError(
-                "engine='oneshot' needs a materialized (T, R) array "
-                "(chunk providers stream via engine='host')")
-        _reject_stream_options(
-            so, "oneshot", "state", "start_sample", "stop_sample",
-            "total_samples")
-        return _condition_fleet_impl(cfg, target, spec, **kwargs)
-    if engine == "host":
-        _reject_stream_options(so, "host", "start_sample", "stop_sample")
-        return _condition_fleet_streaming_impl(
-            cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
-            chunk_intervals=so.chunk_intervals, state=so.state,
-            total_samples=so.total_samples, **kwargs,
-        )
-    if engine == "scanned":
+                "engine='scanned' renders a declarative Scenario/GridRegion "
+                "in-jit; raw trace arrays and chunk providers stream via "
+                "engine='host' (or engine='oneshot' for materialized arrays)")
         raise ValueError(
-            "engine='scanned' renders a declarative Scenario/GridRegion "
-            "in-jit; raw trace arrays and chunk providers stream via "
-            "engine='host' (or engine='oneshot' for materialized arrays)")
-    raise ValueError(
-        f"unknown engine {engine!r} (expected 'scanned', 'host' or 'oneshot')")
+            f"unknown engine {engine!r} (expected 'scanned', 'host' or 'oneshot')")
 
 
 # -------------------------------------------------- deprecated entry points

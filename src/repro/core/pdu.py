@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compliance, controller as ctrl, ess, filters, health as hlt, \
-    safemode as smode, sizing
+    profiling as _prof, safemode as smode, sizing
 from repro.kernels import ops
 from repro.power import faults as flt
 from repro.utils import pytree_dataclass, static_field
@@ -435,9 +435,10 @@ def condition(
     ep = cfg.ess_params
     # Factor-once plan: P, A and the KKT Cholesky depend only on config, so
     # they are hoisted out of the interval scan (and shared by every rack).
-    plan = ctrl.make_plan(cfg.controller, cfg.ess_params) if (
-        cfg.software_enabled and use_plan
-    ) else None
+    with _prof.scope("controller"):
+        plan = ctrl.make_plan(cfg.controller, cfg.ess_params) if (
+            cfg.software_enabled and use_plan
+        ) else None
     hw_kw = dict(
         beta=float(ep.beta), dt=dt, q_max=float(ep.q_max),
         eta_c=float(ep.eta_c), eta_d=float(ep.eta_d),
@@ -638,44 +639,45 @@ def condition(
             wear = jnp.asarray(0.0, jnp.float32)
 
         # --- software path: one controller step --------------------------
-        idle_left = jnp.maximum(
-            jnp.asarray(idle_remaining_s, jnp.float32) - step_idx * k * dt, 0.0
-        )
-        s_target = ctrl.select_target(
-            cfg.controller, cfg.ess_params, idle_left, wear
-        )
-        soc_meas = soc_ema + meas_w * (es2.soc - soc_ema)
+        with _prof.scope("controller"):
+            idle_left = jnp.maximum(
+                jnp.asarray(idle_remaining_s, jnp.float32) - step_idx * k * dt, 0.0
+            )
+            s_target = ctrl.select_target(
+                cfg.controller, cfg.ess_params, idle_left, wear
+            )
+            soc_meas = soc_ema + meas_w * (es2.soc - soc_ema)
 
-        def run_ctrl(soc, up, tgt):
-            out = ctrl.inner_loop_step(
-                cfg.controller, cfg.ess_params, soc, tgt, up, qp_iters=qp_iters
-            )
-            return out.corrective_power, out.qp_primal_residual
+            def run_ctrl(soc, up, tgt):
+                out = ctrl.inner_loop_step(
+                    cfg.controller, cfg.ess_params, soc, tgt, up, qp_iters=qp_iters
+                )
+                return out.corrective_power, out.qp_primal_residual
 
-        if cfg.software_enabled and plan is not None:
-            out, warm2 = ctrl.inner_loop_step_plan(
-                cfg.controller, cfg.ess_params, plan, soc_meas, s_target,
-                u_prev, warm, qp_iters=qp_iters,
-                active=on_row if degraded else None,
-            )
-            new_cmd = out.corrective_power
-            resid = out.qp_primal_residual
-        elif cfg.software_enabled:
-            vec_ctrl = run_ctrl
-            for _ in range(soc_meas.ndim):
-                vec_ctrl = jax.vmap(vec_ctrl)
-            new_cmd, resid = vec_ctrl(
-                soc_meas, jnp.broadcast_to(u_prev, soc_meas.shape),
-                jnp.broadcast_to(s_target, soc_meas.shape),
-            )
-            if degraded:
-                new_cmd = jnp.where(on_row > 0, new_cmd, 0.0)
-                resid = jnp.where(on_row > 0, resid, 0.0)
-            warm2 = warm
-        else:
-            new_cmd = jnp.zeros_like(soc_meas)
-            resid = jnp.zeros_like(soc_meas)
-            warm2 = warm
+            if cfg.software_enabled and plan is not None:
+                out, warm2 = ctrl.inner_loop_step_plan(
+                    cfg.controller, cfg.ess_params, plan, soc_meas, s_target,
+                    u_prev, warm, qp_iters=qp_iters,
+                    active=on_row if degraded else None,
+                )
+                new_cmd = out.corrective_power
+                resid = out.qp_primal_residual
+            elif cfg.software_enabled:
+                vec_ctrl = run_ctrl
+                for _ in range(soc_meas.ndim):
+                    vec_ctrl = jax.vmap(vec_ctrl)
+                new_cmd, resid = vec_ctrl(
+                    soc_meas, jnp.broadcast_to(u_prev, soc_meas.shape),
+                    jnp.broadcast_to(s_target, soc_meas.shape),
+                )
+                if degraded:
+                    new_cmd = jnp.where(on_row > 0, new_cmd, 0.0)
+                    resid = jnp.where(on_row > 0, resid, 0.0)
+                warm2 = warm
+            else:
+                new_cmd = jnp.zeros_like(soc_meas)
+                resid = jnp.zeros_like(soc_meas)
+                warm2 = warm
 
         # --- safe mode: ADMM divergence watchdog -------------------------
         soc_row = es2.soc

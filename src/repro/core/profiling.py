@@ -1,82 +1,36 @@
-"""Host-side phase profiling for the conditioning engines.
+"""The program's tracing: named spans on the host, named scopes on the device.
 
-``benchmarks/run.py --profile`` needs a per-bench phase breakdown
-(render / solve / kernel / host-sync) without dragging in the TensorBoard
-profile toolchain: this module keeps a process-global span accumulator the
-engine host loops annotate.  Spans are no-ops unless ``enable()`` was
-called, so the instrumented sites cost nothing in normal runs; when
-enabled, each span also opens a ``jax.profiler.TraceAnnotation`` so a full
-``jax.profiler.trace`` capture (for deep dives) carries the same phase
-names on its host timeline.
+Two primitives, each under the ``repro.`` prefix so a trace reader can
+tell the program's names from anyone else's:
 
-Measurement model: JAX dispatch is asynchronous, so a wall-clock span
-around a jitted call measures dispatch, not execution.  ``span(name)``
-therefore blocks on the value returned from its body (``sync=...``) before
-closing the clock — profiling deliberately serializes the phases it
-measures.  That makes the phase *sum* close to (slightly above) the
-unprofiled wall clock, which is the right tradeoff for attribution.
+* ``span(name)``: a ``jax.profiler.TraceAnnotation`` for host code.  It
+  writes a TraceMe event on the profiler's host plane, on the same clock
+  as the device's ``XLA Ops`` line, so a reader can name the device's idle
+  gaps by the host phase that was running.  It never blocks: it times the
+  host's own work (checks, dispatch, eager glue), and the device runs on
+  behind it.  With no profiler session open a TraceMe costs next to
+  nothing, so there is no switch.
+* ``scope(name)``: ``jax.named_scope`` for code traced inside a jit.  The
+  name lands in the ``op_name`` metadata of every HLO op the body emits
+  (``jit(run)/while/body/.../repro.render/...``), inside scanned and
+  looped bodies too.  It is metadata only: it changes neither the
+  compiled program's numerics nor its run time.
 
-Only the phases that exist as host-visible stages can be timed this way:
-the streaming host engine renders chunks, dispatches the conditioning
-step, and assembles results on the host, so it is the engine ``--profile``
-re-runs.  Inside the step, the controller solve and the hardware megakernel
-fuse into one program; their split is estimated separately (see
-``benchmarks/run.py``) by timing one eagerly-executed kernel interval.
+PERF.md's "Spans and scopes" table lists each span and scope with the
+measurement that reads it.
 """
 from __future__ import annotations
 
-import contextlib
-import time
-
 import jax
 
-_ENABLED = False
-_PHASES: dict[str, float] = {}
+PREFIX = "repro."
 
 
-def enable() -> None:
-    """Turn spans on and clear any accumulated phase times."""
-    global _ENABLED
-    _ENABLED = True
-    _PHASES.clear()
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """A host span ``repro.<name>`` around the enclosed code."""
+    return jax.profiler.TraceAnnotation(PREFIX + name)
 
 
-def disable() -> None:
-    global _ENABLED
-    _ENABLED = False
-
-
-def enabled() -> bool:
-    return _ENABLED
-
-
-def phases() -> dict[str, float]:
-    """Accumulated seconds per phase since ``enable()``."""
-    return dict(_PHASES)
-
-
-@contextlib.contextmanager
-def span(name: str):
-    """Accumulate wall time under ``name`` (no-op unless enabled).
-
-    The body may hand back a value to block on before the clock closes::
-
-        with profiling.span("solve") as sync:
-            out = step(...)
-            sync(out)
-    """
-    if not _ENABLED:
-        yield lambda x: x
-        return
-    blocked = []
-
-    def sync(x):
-        blocked.append(True)
-        return jax.block_until_ready(x)
-
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(f"repro.{name}"):
-        try:
-            yield sync
-        finally:
-            _PHASES[name] = _PHASES.get(name, 0.0) + (time.perf_counter() - t0)
+def scope(name: str):
+    """A device scope ``repro.<name>`` for the ops traced in the body."""
+    return jax.named_scope(PREFIX + name)
