@@ -12,18 +12,47 @@ so the rack trace is read from HBM exactly once per sample and no
 intermediate (T, R) block (the slewed corrective profile, the wear
 machine's delta stream) round-trips through HBM at all.
 
-Layout: racks tile across lanes (grid = rack tiles of ``r_blk`` lanes;
-one grid step owns its tile end-to-end), time rides the sublane axis with
-the whole interval resident per tile.  VMEM budget per tile at the fleet
-design point (T = 1000 samples, r_blk = 128 lanes, fp32): trace in +
-grid/SoC out = 3 x T x r_blk x 4 B = 1.5 MB, plus (5 + 2x6 + 5) x r_blk
-x 4 B < 12 KB of state — ~1.5 MB single-buffered (~3 MB with the
-pipeline's double buffering, and +0.5 MB each for an optional per-sample
-weight or dense corrective operand), comfortably inside the ~16 MB/core
-VMEM.  Per lane that is ~12 KB of streaming buffer and 88 B of carried
-state — the PR-5 "14-carry spill" was an XLA:CPU *register/L1* pathology
-of one wide scan body; here the carries are explicit VMEM rows and never
-touch the stack.
+Layout: racks fill whole vregs at every time step.  The wrapper splits R
+into G = ceil(R / 128) groups of 128 lanes and hands the kernel each
+(T, R) operand and output as (T, G, 128); per-rack rows become (G, 128)
+and stacks of them (n, G, 128).  A block is (tc, s, 128): ``r_ref[t]``
+is one dense (s, 128) value, s sublane rows of racks, and every carried
+state row is (s, 128) too.  The shape alone picks s (``_tiling``).  A
+campus of one group (128 racks or fewer) keeps the group axis out: its
+operands stay (T, 128) in dense (tc, 128) blocks, time on the sublanes
+and tc a multiple of 8, and every state row is one (128,) lane row.  Up
+to 8 groups take s = G, the full dimension.  A wider campus takes s = 8
+x c, c <= ``_VREGS_PER_STEP`` independent (8, 128) vregs per step body,
+spread evenly over the fewest blocks, with G padded to a multiple of s.
+The step body is a latency-bound chain of small VPU ops, so c vregs ride
+each op's issue slot and latency where one sublane did.
+
+Grid (G / s, ceil(T / tc)): rack blocks outer, time chunks inner and
+``"arbitrary"``.  The carries live in the (5, s, 128) / (6, s, 128)
+final-state output blocks, whose index does not move along time: they
+are seeded from the initial-state operands at the first chunk, stay in
+VMEM across the chunks and are written back once per rack block.  A
+ragged last chunk runs its own static trip count, so the time pad the
+wrapper adds is never stepped through.
+
+VMEM budget: every block of a grid step is double-buffered, and all of
+them together stay within ``_BLOCK_VMEM`` = 14 MiB, 2 MiB under the
+v5e's 16 MiB default scoped limit.  The row operands — initial state,
+slew rows, the 1-D weight or the (E, ·) event tables and their base
+row, the health machine, and the final-state outputs, n = 10 + 2 + (1 or
+2E + 1) + 12 rows at most — take n x round_up(s, 8) x 128 x 4 B each.  They may fill at most half
+the budget, else c shrinks (down to one vreg); tc is then the largest
+chunk that fits the (T, ·) streams — the trace, the grid and SoC
+outputs, plus the dense corrective and the per-sample weight when
+present — into the rest, at tc x round_up(s, 8) x 128 x 4 B each (tc x
+128 x 4 B for one group).  Only rows that leave less than a quarter of
+the budget to the streams (more than about 660 episodes) raise
+``vmem_limit_bytes`` to what the blocks need.
+At the benchmark campus (T = 1000, R = 4000, slew + health: three
+streams, 24 rows) s = 32 and tc = 125: rows 0.75 MiB, eight chunks of
+2 MiB per stream, 12.5 MiB in all.  With ``faults.MAX_EPISODES`` = 512
+episodes the same campus takes s = 8 and tc = 200 (rows 8.2 MiB, 12.9
+MiB in all).
 
 Bitwise contract (the PR-5 reproducibility contract, verified in
 ``tests/test_pdu_health_kernel.py`` against ``ref.pdu_health_sim`` in
@@ -52,10 +81,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+LANES, SUBLANES = 128, 8
+# Most independent (8, 128) vregs one step body carries (s <= 8 x this).
+# The step is a latency chain, so its time barely grows with the vregs it
+# carries: one v5e interval of 4000 racks took 0.59 / 0.29 / 0.16 ms of
+# kernel time at 1 / 2 / 4.
+_VREGS_PER_STEP = 4
+# VMEM for the double-buffered blocks of one grid step, streams and rows
+# together: 2 MiB under the v5e's 16 MiB default scoped limit is left to
+# Mosaic's own scratch.
+_SCOPED_VMEM = 16 * 2**20
+_BLOCK_VMEM = 14 * 2**20
+
+
+def _tiling(
+    t: int, r: int, n_streams: int, n_rows: int
+) -> tuple[int, int, int, int, int | None]:
+    """Block shape from the problem's shape alone: ``(s, g_pad, tc, n_t,
+    vmem_limit)`` for ``t`` samples of ``r`` racks, ``n_streams`` (T, ·)
+    streams and ``n_rows`` per-rack rows (state, slew, mask, event tables
+    and final-state outputs together) — s sublane rows of 128 racks per
+    block, the group count padded to a multiple of s, the interval cut
+    into n_t chunks of tc samples, and the scoped VMEM limit to ask for
+    (None: the default holds every block)."""
+    g = -(-r // LANES)
+    # One vreg of racks across every row operand, double-buffered.
+    row_vreg = 2 * n_rows * 4 * LANES * SUBLANES
+    if g == 1:
+        # One group: time rides the sublanes of a dense (tc, 128) block.
+        s, align, step_bytes = 1, SUBLANES, 4 * LANES
+    elif g <= SUBLANES:
+        s, align, step_bytes = g, 1, 4 * LANES * SUBLANES
+    else:
+        # Rows take at most half the blocks' VMEM: long episode tables
+        # cost vregs per step rather than time chunk length.
+        per_step = min(_VREGS_PER_STEP, max(1, _BLOCK_VMEM // 2 // row_vreg))
+        vregs = -(-g // SUBLANES)
+        blocks = -(-vregs // per_step)
+        s = SUBLANES * -(-vregs // blocks)
+        align, step_bytes = 1, 4 * LANES * s
+    rows_bytes = row_vreg * -(-s // SUBLANES)
+    room = max(_BLOCK_VMEM - rows_bytes, _BLOCK_VMEM // 4)
+    tc_max = max(align, room // (2 * n_streams * step_bytes) // align * align)
+    n_t = -(-t // tc_max)
+    tc = -(-t // (n_t * align)) * align
+    need = rows_bytes + 2 * n_streams * step_bytes * tc
+    limit = None if need <= _BLOCK_VMEM else need + _SCOPED_VMEM - _BLOCK_VMEM
+    return s, -(-g // s) * s, tc, n_t, limit
+
 
 def _megakernel(
     *refs,
     t_total: int,
+    t_chunk: int,
+    n_chunks: int,
     dt: float,
     q_max: float,
     eta_c: float,
@@ -82,49 +161,57 @@ def _megakernel(
     grid_ref, soc_ref, sf_ref = (next(it) for _ in range(3))
     hf_ref = next(it) if track_health else None
 
+    # The final-state blocks carry the state across time chunks: their
+    # block index does not move along the time axis, so they stay in VMEM
+    # from the first chunk (seeded here) to the last.
+    chunk = pl.program_id(1)
+
+    @pl.when(chunk == 0)
+    def _seed():
+        sf_ref[...] = s0_ref[...]
+        if track_health:
+            hf_ref[...] = h0_ref[...]
+
+    t0 = chunk * t_chunk
     a = ad_ref[...]
     b = bd_ref[...]
     c = c_ref[...]
     alpha = al_ref[0, 0]
-    w_row = on_ref[0, :] if (masked and not mask_2d and not events) else None
+    w_row = on_ref[0] if (masked and not mask_2d and not events) else None
     if events:
-        # Compact episode-table operand: (E, r_blk) sorted int32 boundary
-        # tables + a (r_blk,) base availability row, resident in VMEM for
-        # the whole interval — replaces the streamed (T, r_blk) weight
+        # Compact episode-table operand: (E, s, 128) sorted int32 boundary
+        # tables + an (s, 128) base availability row, resident in VMEM for
+        # the whole interval — replaces the streamed (T, s, 128) weight
         # block (HBM traffic O(E + 1) rows instead of O(T)).
-        ev_st = ev_st_ref[...]
-        ev_en = ev_en_ref[...]
-        ev_base = base_ref[0, :]
+        n_ev = ev_st_ref.shape[0]
+        ev_base = base_ref[0]
         ev_i0 = iev_ref[0, 0]
         ev_tlast = iev_ref[0, 1]
     if slew:
-        applied = corr_ref[0, :]
-        diff = corr_ref[1, :]
+        applied = corr_ref[0]
+        diff = corr_ref[1]
     if track_health:
         c0, c1, eps, kappa = hconsts
 
     def events_weight(t):
         # Per-step ESS availability from boundary events, the identical
         # clip/where arithmetic as faults.ess_weight (rows sorted, so
-        # "entry j <= idx" == "count >= j+1" — same boundary selection as
+        # "entry e <= idx" == "count >= e+1" — same boundary selection as
         # faults._select_boundaries, bitwise).  Clamping the absolute
         # index to the last real sample replicates the streamed path's
         # zero-order-hold repeat-padding.
         idx_t = jnp.minimum(ev_i0 + t, ev_tlast)
-        started = [ev_st[j, :] <= idx_t for j in range(ev_st.shape[0])]
+        started = [ev_st_ref[e] <= idx_t for e in range(n_ev)]
         if ess_edge <= 1:
             s_cnt = sum(s.astype(jnp.int32) for s in started)
-            e_cnt = sum(
-                (ev_en[j, :] <= idx_t).astype(jnp.int32)
-                for j in range(ev_en.shape[0])
-            )
+            e_cnt = sum((ev_en_ref[e] <= idx_t).astype(jnp.int32) for e in range(n_ev))
             intensity = ((s_cnt - e_cnt) > 0).astype(jnp.float32)
         else:
             inv = 1.0 / float(ess_edge)
-            st_sel, en_sel = ev_st[0, :], ev_en[0, :]
-            for j in range(1, ev_st.shape[0]):
-                st_sel = jnp.where(started[j], ev_st[j, :], st_sel)
-                en_sel = jnp.where(started[j], ev_en[j, :], en_sel)
+            st_sel, en_sel = ev_st_ref[0], ev_en_ref[0]
+            for e in range(1, n_ev):
+                st_sel = jnp.where(started[e], ev_st_ref[e], st_sel)
+                en_sel = jnp.where(started[e], ev_en_ref[e], en_sel)
             wa = (idx_t - st_sel).astype(jnp.float32)
             wb = (idx_t - en_sel).astype(jnp.float32)
             w = jnp.clip((wa + 1.0) * inv, 0.0, 1.0) - jnp.clip(
@@ -135,19 +222,20 @@ def _megakernel(
 
     def step(t, carry):
         g, soc, x0, x1, x2, hm = carry
-        r_t = r_ref[t, :]
+        t_abs = t0 + t
+        r_t = r_ref[t]
         if slew:
             # ramp = (t+1)/T, the identical fused expression the reference
             # evaluates from its arange — the slewed corrective profile is
             # rendered in-register instead of streamed from HBM.
-            c_t = applied + diff * ((t + 1).astype(jnp.float32) / t_total)
+            c_t = applied + diff * ((t_abs + 1).astype(jnp.float32) / t_total)
         else:
-            c_t = corr_ref[t, :]
+            c_t = corr_ref[t]
         if masked:
             if events:
-                w_t = events_weight(t)
+                w_t = events_weight(t_abs)
             else:
-                w_t = on_ref[t, :] if mask_2d else w_row
+                w_t = on_ref[t] if mask_2d else w_row
         # --- ESS ramp control (paper Eq. 2, exact ZOH) --------------------
         g_new = g + alpha * (r_t - g)
         if masked:
@@ -167,10 +255,10 @@ def _megakernel(
             soc_new = jnp.where(w_t > 0, soc_new, soc)
         node = r_t + p_batt
         # --- LC filter (grid current out, state update) --------------------
-        grid_ref[t, :] = (c[0, 0] * x0 + c[0, 1] * x1 + c[0, 2] * x2).astype(
+        grid_ref[t] = (c[0, 0] * x0 + c[0, 1] * x1 + c[0, 2] * x2).astype(
             grid_ref.dtype
         )
-        soc_ref[t, :] = soc_new
+        soc_ref[t] = soc_new
         x0n = a[0, 0] * x0 + a[0, 1] * x1 + a[0, 2] * x2 + b[0, 1] * node + b[0, 0]
         x1n = a[1, 0] * x0 + a[1, 1] * x1 + a[1, 2] * x2 + b[1, 1] * node + b[1, 0]
         x2n = a[2, 0] * x0 + a[2, 1] * x1 + a[2, 2] * x2 + b[2, 1] * node + b[2, 0]
@@ -204,19 +292,29 @@ def _megakernel(
             )
         return (g_new, soc_new, x0n, x1n, x2n, hm)
 
-    hm0 = tuple(h0_ref[i, :] for i in range(6)) if track_health else ()
-    carry0 = (s0_ref[0, :], s0_ref[1, :], s0_ref[2, :], s0_ref[3, :], s0_ref[4, :], hm0)
-    g, soc, x0, x1, x2, hm = jax.lax.fori_loop(0, t_total, step, carry0)
-    sf_ref[...] = jnp.stack([g, soc, x0, x1, x2], axis=0)
-    if track_health:
-        hf_ref[...] = jnp.stack([hm[0], hm[1], hm[2], hm[3], hm[4], hm[5]], axis=0)
+    def run(n):
+        hm0 = tuple(hf_ref[i] for i in range(6)) if track_health else ()
+        carry0 = tuple(sf_ref[i] for i in range(5)) + (hm0,)
+        *state, hm = jax.lax.fori_loop(0, n, step, carry0)
+        for i, v in enumerate(state):
+            sf_ref[i] = v
+        for i, v in enumerate(hm):
+            hf_ref[i] = v
+
+    # A ragged last chunk steps only through its real samples.
+    t_last = t_total - (n_chunks - 1) * t_chunk
+    if n_chunks == 1 or t_last == t_chunk:
+        run(t_last)
+    else:
+        pl.when(chunk < n_chunks - 1)(lambda: run(t_chunk))
+        pl.when(chunk == n_chunks - 1)(lambda: run(t_last))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "beta", "dt", "q_max", "eta_c", "eta_d", "p_max", "soc_min", "soc_max",
-        "health_consts", "ess_edge", "r_blk", "interpret",
+        "health_consts", "ess_edge", "interpret",
     ),
 )
 def pdu_health_sim(
@@ -243,7 +341,6 @@ def pdu_health_sim(
     ess_edge: int = 1,
     health_consts: tuple | None = None,  # (c0, c1, eps, kappa) host floats
     health_state: tuple | None = None,  # 11 HealthState leaves, (R,) each
-    r_blk: int = 128,
     interpret: bool = False,
 ):
     """Interval-resident megakernel.  Same contract as ``ref.pdu_health_sim``
@@ -260,127 +357,125 @@ def pdu_health_sim(
         raise ValueError("pass either ess_on or ess_events, not both")
     masked = ess_on is not None or events
     mask_2d = ess_on is not None and ess_on.ndim == 2
-    r_pad = -r % r_blk
-    rp_w = r + r_pad
-    t_pad = -t % 8  # sublane-align the time axis; the loop stops at t
+    n_streams = 3 + (slew is None) + mask_2d
+    n_ev = ess_events[0].shape[0] if events else 0
+    n_rows = (
+        10 + 2 * (slew is not None) + (masked and not mask_2d and not events)
+        + (2 * n_ev + 1 if events else 0) + 12 * track_health
+    )
+    s, g_pad, tc, n_t, vmem_limit = _tiling(t, r, n_streams, n_rows)
+    r_pad = g_pad * LANES - r
+    t_pad = n_t * tc - t
     f32 = jnp.float32
+    # Racks of one array row: (G, 128), or (128,) for a single group.
+    racks = (g_pad, LANES) if g_pad > 1 else (LANES,)
+    block = (s, LANES) if g_pad > 1 else (LANES,)
 
-    def pad_tr(x):  # (T, R) operand -> (T + t_pad, R + r_pad)
-        x = x.astype(f32)
-        if r_pad:
-            x = jnp.pad(x, ((0, 0), (0, r_pad)))
-        if t_pad:
-            x = jnp.pad(x, ((0, t_pad), (0, 0)))
-        return x
+    def tiles_tr(x):  # (T, R) operand -> (T + t_pad, *racks)
+        x = jnp.pad(x.astype(f32), ((0, t_pad), (0, r_pad)))
+        return x.reshape(t + t_pad, *racks)
 
-    def pad_r(x):  # (R,) row -> (R + r_pad,)
-        x = jnp.broadcast_to(x, (r,)).astype(f32)
-        return jnp.pad(x, (0, r_pad)) if r_pad else x
+    def tiles_r(x, fill=0):  # (n, R) rows -> (n, *racks)
+        x = jnp.pad(x, ((0, 0), (0, r_pad)), constant_values=fill)
+        return x.reshape(x.shape[0], *racks)
+
+    def rows(*xs):  # (R,) rows -> (len(xs), *racks)
+        return tiles_r(jnp.stack([jnp.broadcast_to(x, (r,)).astype(f32) for x in xs]))
+
+    def stream_spec():
+        return pl.BlockSpec((tc, *block), lambda i, j: (j, i, 0)[: 1 + len(block)])
+
+    def rows_spec(n):
+        return pl.BlockSpec((n, *block), lambda i, j: (0, i, 0)[: 1 + len(block)])
+
+    def const_spec(shape):
+        return pl.BlockSpec(shape, lambda i, j: (0, 0))
 
     # alpha is traced with the exact expression the reference evaluates —
     # a 1-ulp difference (e.g. from host-side float64 exp) shows up as ulp
     # drift across the whole grid/LC path.
     alpha = (1.0 - jnp.exp(-jnp.asarray(beta, jnp.float32) * dt)).reshape(1, 1)
-    s0 = jnp.stack([pad_r(g0), pad_r(soc0)] + [pad_r(x0[:, i]) for i in range(3)])
-    const_specs = [
-        pl.BlockSpec((3, 3), lambda i: (0, 0)),
-        pl.BlockSpec((3, 2), lambda i: (0, 0)),
-        pl.BlockSpec((1, 3), lambda i: (0, 0)),
-        pl.BlockSpec((1, 1), lambda i: (0, 0)),
+    in_specs = [
+        const_spec((3, 3)), const_spec((3, 2)), const_spec((1, 3)), const_spec((1, 1)),
+        rows_spec(5), stream_spec(),
     ]
-    operands = [ad.astype(f32), bd.astype(f32), c_row.reshape(1, 3).astype(f32), alpha]
-    in_specs = const_specs + [
-        pl.BlockSpec((5, r_blk), lambda i: (0, i)),
-        pl.BlockSpec((t + t_pad, r_blk), lambda i: (0, i)),
+    operands = [
+        ad.astype(f32), bd.astype(f32), c_row.reshape(1, 3).astype(f32), alpha,
+        rows(g0, soc0, *(x0[:, i] for i in range(3))), tiles_tr(rack_power),
     ]
-    operands += [s0, pad_tr(rack_power)]
     if slew is not None:
-        applied, target = slew
-        applied = pad_r(applied)
-        corr_op = jnp.stack([applied, pad_r(target) - applied], axis=0)  # (2, Rp)
-        in_specs.append(pl.BlockSpec((2, r_blk), lambda i: (0, i)))
+        applied, target = (jnp.broadcast_to(x, (r,)).astype(f32) for x in slew)
+        in_specs.append(rows_spec(2))
+        operands.append(rows(applied, target - applied))
     else:
-        corr_op = pad_tr(jnp.broadcast_to(jnp.asarray(corrective, f32), (t, r)))
-        in_specs.append(pl.BlockSpec((t + t_pad, r_blk), lambda i: (0, i)))
-    operands.append(corr_op)
+        in_specs.append(stream_spec())
+        operands.append(tiles_tr(jnp.broadcast_to(jnp.asarray(corrective, f32), (t, r))))
     if mask_2d:
-        in_specs.append(pl.BlockSpec((t + t_pad, r_blk), lambda i: (0, i)))
-        operands.append(pad_tr(ess_on))
+        in_specs.append(stream_spec())
+        operands.append(tiles_tr(ess_on))
     elif masked and not events:
-        in_specs.append(pl.BlockSpec((1, r_blk), lambda i: (0, i)))
-        operands.append(pad_r(ess_on).reshape(1, rp_w))
+        in_specs.append(rows_spec(1))
+        operands.append(rows(ess_on))
     if events:
         ev_st, ev_en, ev_base, ev_i0, ev_tlast = ess_events
-
-        def pad_ri(x):  # (E, R) int32 table -> (E, R + r_pad), pad = never
-            x = jnp.asarray(x, jnp.int32)
-            if r_pad:
-                x = jnp.pad(
-                    x, ((0, 0), (0, r_pad)),
-                    constant_values=jnp.iinfo(jnp.int32).max,
-                )
-            return x
-
-        n_ev = ev_st.shape[0]
-        in_specs += [
-            pl.BlockSpec((n_ev, r_blk), lambda i: (0, i)),
-            pl.BlockSpec((n_ev, r_blk), lambda i: (0, i)),
-            pl.BlockSpec((1, r_blk), lambda i: (0, i)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-        ]
+        never = jnp.iinfo(jnp.int32).max  # padded racks: no episode
+        in_specs += [rows_spec(n_ev), rows_spec(n_ev), rows_spec(1), const_spec((1, 2))]
         operands += [
-            pad_ri(ev_st),
-            pad_ri(ev_en),
-            pad_r(ev_base).reshape(1, rp_w),
+            tiles_r(jnp.asarray(ev_st, jnp.int32), never),
+            tiles_r(jnp.asarray(ev_en, jnp.int32), never),
+            rows(ev_base),
             jnp.stack(
                 [jnp.asarray(ev_i0, jnp.int32), jnp.asarray(ev_tlast, jnp.int32)]
             ).reshape(1, 2),
         ]
     if track_health:
-        h0 = jnp.stack([pad_r(l) for l in health_state[:6]], axis=0)  # (6, Rp)
-        in_specs.append(pl.BlockSpec((6, r_blk), lambda i: (0, i)))
-        operands.append(h0)
+        in_specs.append(rows_spec(6))
+        operands.append(rows(*health_state[:6]))
 
-    out_specs = [
-        pl.BlockSpec((t + t_pad, r_blk), lambda i: (0, i)),
-        pl.BlockSpec((t + t_pad, r_blk), lambda i: (0, i)),
-        pl.BlockSpec((5, r_blk), lambda i: (0, i)),
-    ]
+    stream_shape = (t + t_pad, *racks)
+    out_specs = [stream_spec(), stream_spec(), rows_spec(5)]
     out_shape = [
-        jax.ShapeDtypeStruct((t + t_pad, rp_w), rack_power.dtype),
-        jax.ShapeDtypeStruct((t + t_pad, rp_w), f32),
-        jax.ShapeDtypeStruct((5, rp_w), f32),
+        jax.ShapeDtypeStruct(stream_shape, rack_power.dtype),
+        jax.ShapeDtypeStruct(stream_shape, f32),
+        jax.ShapeDtypeStruct((5, *racks), f32),
     ]
     if track_health:
-        out_specs.append(pl.BlockSpec((6, r_blk), lambda i: (0, i)))
-        out_shape.append(jax.ShapeDtypeStruct((6, rp_w), f32))
+        out_specs.append(rows_spec(6))
+        out_shape.append(jax.ShapeDtypeStruct((6, *racks), f32))
 
     outs = pl.pallas_call(
         functools.partial(
             _megakernel,
-            t_total=t, dt=dt, q_max=q_max, eta_c=eta_c,
+            t_total=t, t_chunk=tc, n_chunks=n_t, dt=dt, q_max=q_max, eta_c=eta_c,
             eta_d=eta_d, p_max=p_max, soc_min=soc_min, soc_max=soc_max,
             masked=masked, mask_2d=mask_2d, events=events, ess_edge=ess_edge,
             slew=slew is not None,
             track_health=track_health, hconsts=health_consts,
         ),
-        grid=(rp_w // r_blk,),
+        grid=(g_pad // s, n_t),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit,
+        ),
         interpret=interpret,
     )(*operands)
-    grid_t, soc_t, sf = outs[0][:t, :r], outs[1][:t, :r], outs[2][:, :r]
+
+    def untile(x, n):  # (n, *racks) -> (n, R)
+        return x.reshape(x.shape[0], g_pad * LANES)[:n, :r]
+
+    grid_t, soc_t, sf = untile(outs[0], t), untile(outs[1], t), untile(outs[2], 5)
     finals = (sf[0], sf[1], sf[2:5].T)
     if not track_health:
         return grid_t, soc_t, finals, None
-    hf = outs[3][:, :r]
+    hf = untile(outs[3], 6)
     # Block accumulators: the reference's whole-interval reductions,
     # verbatim, over the sliced (t, r) SoC path — deliberately OUTSIDE the
     # kernel so the reduce shape (and therefore XLA's accumulator
     # splitting) matches the reference for every fleet width; reducing the
-    # padded (t, r_blk) tile in-kernel reassociates by 1 ulp at narrow
+    # padded (t, 128) tile in-kernel reassociates by 1 ulp at narrow
     # widths.  XLA fuses this epilogue with the kernel's soc_t output.
     prev_soc = jnp.broadcast_to(health_state[0], (r,)).astype(f32)
     prev_t = jnp.concatenate(

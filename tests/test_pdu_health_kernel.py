@@ -5,14 +5,17 @@ Runs the Pallas kernels in interpret mode against their jnp oracles
 dispatch layer, pinning the PR-5 reproducibility contract:
 
 * SoC path, ESS filter value and **every** health leaf: bitwise.
-* Grid / LC filter state: bitwise on sublane-aligned intervals; a few
-  ulp on ragged intervals (XLA contracts the LC mul-add chain into FMAs
-  differently once the time axis is padded — see the kernel docstring).
+* Grid / LC filter state: bitwise against the jitted reference; a few
+  ulp against the eagerly evaluated one on some interval lengths (XLA
+  contracts the LC mul-add chain into FMAs differently — see the kernel
+  docstring).
 * Degraded-mode weights w in {0, 1}: bitwise against the same masked
   reference path the engines run.
 * The turning-point machine and block accumulators: bitwise under
   stream splits (kernel-of-halves == kernel-of-whole == reference).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -20,8 +23,8 @@ import pytest
 
 from repro.core import controller as ctrl, health as hlt, pdu
 from repro.core.ess import ESSParams
-from repro.kernels import ops, ref
-from repro.power import scenario as SC
+from repro.kernels import ops, pdu_health, ref
+from repro.power import faults as flt, scenario as SC
 
 pytestmark = pytest.mark.pallas
 
@@ -132,9 +135,10 @@ def test_dense_and_scalar_corrective_parity():
 
 
 def test_ragged_final_interval():
-    """t = 37 stresses the sublane pad: the loop must stop at t, padding
-    rows must never leak into the block reductions, and the contract
-    degrades only on the grid/LC path (ulp; see kernel docstring)."""
+    """t = 37, a prime interval: the loop must stop at t, padding must
+    never leak into the block reductions, and against the eager reference
+    the contract degrades only on the grid/LC path (ulp; see kernel
+    docstring)."""
     chunk, args, kw, health = _setup(37)
     r1 = ref.pdu_health_sim(*([chunk] + list(args)), slew=_slew(), health=health, **kw)
     r2 = ops.pdu_health_sim(
@@ -143,17 +147,116 @@ def test_ragged_final_interval():
     _assert_parity(r1, r2, grid_bitwise=False)
 
 
-def test_multi_tile_and_rack_padding():
-    """R = 192 with r_blk = 64: three full tiles; r_blk = 128: one full +
-    one padded tile.  Tiling must not change a single bit."""
-    chunk, args, kw, health = _setup(40)
-    r1 = ref.pdu_health_sim(*([chunk] + list(args)), slew=_slew(), health=health, **kw)
-    for blk in (64, 128):
+def _jit_ref(*args, health=None, **kw):
+    """The reference as the engines run it: jitted, so XLA fuses the
+    health epilogue's square-and-sum as it does the kernel wrapper's.
+    (Evaluated eagerly, op by op, that sum rounds differently from the
+    fused one for intervals of 32 samples or fewer.)"""
+    fn = functools.partial(ref.pdu_health_sim, health=health, **kw)
+    return jax.jit(fn)(*args)
+
+
+def _events(t, n_racks, n_ev=3, i0=5):
+    """Sorted, non-overlapping (E, R) episode tables, a base row, and an
+    absolute index window whose last real sample clamps two steps early."""
+    kg, kl, kb = jax.random.split(jax.random.key(10), 3)
+    gaps = jax.random.randint(kg, (n_ev, n_racks), 0, 9)
+    lens = jax.random.randint(kl, (n_ev, n_racks), 1, 7)
+    ends = jnp.cumsum(gaps + lens, axis=0)
+    base = (jax.random.uniform(kb, (n_racks,)) > 0.2).astype(jnp.float32)
+    return (ends - lens, ends, base, jnp.int32(i0), jnp.int32(i0 + t - 3))
+
+
+def _variant_kw(variant, t, n_racks):
+    """Keyword arguments of one megakernel variant (ref and kernel alike)."""
+    if variant == "unmasked":
+        return dict(slew=_slew(n_racks))
+    if variant == "mask_2d":
+        return dict(slew=_slew(n_racks), ess_on=jax.random.uniform(
+            jax.random.key(8), (t, n_racks)))
+    if variant == "ess_events":
+        return dict(slew=_slew(n_racks), ess_events=_events(t, n_racks), ess_edge=4)
+    if variant == "dense_corrective":
+        return dict(corrective=0.02 * jax.random.normal(
+            jax.random.key(9), (t, n_racks), jnp.float32))
+    raise ValueError(variant)
+
+
+@pytest.mark.parametrize("n_racks", [100, 1000, 2100])
+@pytest.mark.parametrize(
+    "variant", ["unmasked", "mask_2d", "ess_events", "dense_corrective", "no_health"])
+def test_sublane_tiling_parity(variant, n_racks):
+    """Racks fill (s, 128) tiles per time step: one partial group (100),
+    eight groups with ragged lanes (1000), 17 groups padded to a 24-row
+    block (2100); t = 21 is ragged against every sublane count.  Every
+    variant stays bitwise against the jitted reference, grid included."""
+    t = 21
+    chunk, args, kw, health = _setup(t, n_racks)
+    if variant == "no_health":
+        health, vkw = None, _variant_kw("unmasked", t, n_racks)
+    else:
+        vkw = _variant_kw(variant, t, n_racks)
+    r1 = _jit_ref(chunk, *args, health=health, **vkw, **kw)
+    r2 = ops.pdu_health_sim(chunk, *args, health=health, force="pallas", **vkw, **kw)
+    _assert_parity(r1, r2, grid_bitwise=True)
+
+
+# Rows of the slew + health variant, and of its episode-table form at the
+# fault process's episode cap (two tables of E rows and a base row more).
+_ROWS = 24
+_MAX_ROWS = _ROWS + 2 * flt.MAX_EPISODES + 1
+
+
+@pytest.mark.parametrize("n_racks,n_rows,s,g_pad", [
+    (1, _ROWS, 1, 1), (128, _ROWS, 1, 1), (129, _ROWS, 2, 2),
+    (1000, _ROWS, 8, 8), (1024, _ROWS, 8, 8), (1025, _ROWS, 16, 16),
+    (2100, _ROWS, 24, 24), (4000, _ROWS, 32, 32), (4200, _ROWS, 24, 48),
+    (100, _MAX_ROWS, 1, 1), (1024, _MAX_ROWS, 8, 8), (4000, _MAX_ROWS, 8, 32),
+    (4000, _MAX_ROWS + 2000, 8, 32),
+])
+def test_tiling_rule(n_racks, n_rows, s, g_pad):
+    """s follows the shape alone: one group keeps time on the sublanes of a
+    (tc, 128) block, up to 8 groups take the full group count, more take
+    up to four (8, 128) vregs per block, spread evenly over the fewest
+    blocks, and fewer when the row operands would fill half the VMEM
+    budget.  Every block fits the default scoped VMEM up to the episode
+    cap; beyond it the limit is raised to what the blocks need."""
+    got_s, got_g, tc, n_t, limit = pdu_health._tiling(1000, n_racks, 3, n_rows)
+    assert (got_s, got_g) == (s, g_pad)
+    assert n_t * tc >= 1000 > (n_t - 1) * tc
+    tile_rows = -(-s // 8) * 8
+    step = 4 * 128 * (tile_rows if s > 1 else 1)
+    if s == 1:
+        assert tc % 8 == 0
+    need = 2 * n_rows * 4 * 128 * tile_rows + 2 * 3 * step * tc
+    assert (limit is None) == (n_rows <= _MAX_ROWS)
+    assert need <= (limit or pdu_health._BLOCK_VMEM)
+
+
+@pytest.mark.parametrize("n_racks,budget,tiling", [
+    # 33 groups in two 24-row rack blocks, 15 rows padded; chunks of 11
+    # and a ragged 10.
+    (4200, 2 * _ROWS * 4 * 128 * 24 + 2 * 3 * 11 * 4 * 128 * 24, (24, 48, 11, 2)),
+    # One group, time on the sublanes: chunks of 16 and a ragged 5.
+    (100, 2 * _ROWS * 4 * 128 * 8 + 2 * 3 * 16 * 4 * 128 + 4096, (1, 1, 16, 2)),
+])
+def test_multi_tile_and_rack_padding(monkeypatch, n_racks, budget, tiling):
+    """With the VMEM budget shrunk, t = 21 runs as two time chunks with a
+    ragged last one, over rack blocks or the one-group layout.  Tiling
+    must not change a single bit."""
+    t = 21
+    monkeypatch.setattr(pdu_health, "_BLOCK_VMEM", budget)
+    assert pdu_health._tiling(t, n_racks, 3, _ROWS) == (*tiling, None)
+    pdu_health.pdu_health_sim.clear_cache()
+    try:
+        chunk, args, kw, health = _setup(t, n_racks)
+        r1 = _jit_ref(chunk, *args, slew=_slew(n_racks), health=health, **kw)
         r2 = ops.pdu_health_sim(
-            *([chunk] + list(args)), slew=_slew(), health=health,
-            force="pallas", r_blk=blk, **kw
+            chunk, *args, slew=_slew(n_racks), health=health, force="pallas", **kw
         )
         _assert_parity(r1, r2, grid_bitwise=True)
+    finally:
+        pdu_health.pdu_health_sim.clear_cache()
 
 
 def test_no_health_path():

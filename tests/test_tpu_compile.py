@@ -3,9 +3,13 @@
 Compiles the interval megakernel (``kernels/pdu_health.py``) and the
 batched-ADMM kernel (``kernels/admm_step.py``) for a described, not
 attached, v5e chip at the fleet design point: k = 1000 samples per
-controller interval, R = 1024 racks, f32.  Nothing runs; the TPU compiler
-refuses here what interpret mode cannot see (unaligned slices, VMEM over
-budget, unsupported lowerings), and each kernel must come out as a Mosaic
+controller interval, R = 1024 racks (one (8, 128) rack tile) and, for
+the megakernel, R = 4000 (the benchmark campus: 32 groups of 128 racks,
+padded from 4000) and R = 100 (one group: time on the sublanes), f32;
+the episode-table variant also at the fault process's episode cap and
+past it.  Nothing runs; the TPU compiler refuses here what interpret
+mode cannot see (unaligned slices, VMEM over budget, unsupported
+lowerings), and each kernel must come out as a Mosaic
 ``tpu_custom_call``.
 
 The topology is described inside a module-scoped fixture, never at import:
@@ -22,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import controller as ctrl, health as hlt, pdu
 from repro.kernels import admm_step, pdu_health
+from repro.power import faults as flt
 
 K, R, HZ = 1000, 1024, 200.0
 H, ITERS = 12, 30
@@ -55,13 +60,13 @@ def _spec(x, sharding):
     return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
-def _megakernel_args(variant, sharding):
+def _megakernel_args(variant, sharding, n_racks, n_events=N_EVENTS):
     cfg = pdu.make_pdu(sample_dt=1.0 / HZ, track_health=True)
-    st = pdu.init_state(cfg, jnp.full((R,), 0.5, jnp.float32))
+    st = pdu.init_state(cfg, jnp.full((n_racks,), 0.5, jnp.float32))
     ep, filt = cfg.ess_params, st.filter_obj
     spec = lambda x: _spec(x, sharding)
     args = (
-        jax.ShapeDtypeStruct((K, R), jnp.float32, sharding=sharding),
+        jax.ShapeDtypeStruct((K, n_racks), jnp.float32, sharding=sharding),
         spec(st.ess_state.g_filter), spec(st.ess_state.soc),
         spec(st.filter_state), spec(filt.ad), spec(filt.bd), spec(filt.c[0]),
     )
@@ -75,27 +80,40 @@ def _megakernel_args(variant, sharding):
         kw["health_consts"] = hlt.step_consts(cfg.health)
         kw["health_state"] = tuple(spec(x) for x in st.health)
     elif variant == "ess_events":
-        table = jax.ShapeDtypeStruct((N_EVENTS, R), jnp.int32, sharding=sharding)
+        table = jax.ShapeDtypeStruct((n_events, n_racks), jnp.int32, sharding=sharding)
         idx = jax.ShapeDtypeStruct((), jnp.int32, sharding=sharding)
         kw["slew"] = (spec(st.cmd_applied), spec(st.cmd_target))
         kw["ess_events"] = (table, table, spec(st.ess_online), idx, idx)
         kw["ess_edge"] = 7
     elif variant == "ess_on_2d":
         kw["slew"] = (spec(st.cmd_applied), spec(st.cmd_target))
-        kw["ess_on"] = jax.ShapeDtypeStruct((K, R), jnp.float32, sharding=sharding)
+        kw["ess_on"] = jax.ShapeDtypeStruct((K, n_racks), jnp.float32, sharding=sharding)
     else:
         raise ValueError(variant)
     return args, kw
 
 
-@pytest.mark.parametrize("variant", ["slew_health", "ess_events", "ess_on_2d"])
-def test_megakernel_compiles_for_v5e(one_chip, variant):
-    args, kw = _megakernel_args(variant, one_chip)
+@pytest.mark.parametrize("variant,n_racks,n_events", [
+    pytest.param(v, n, N_EVENTS, id=v if n == R else f"{v}-{n}")
+    for n in (R, 4000, 100) for v in ("slew_health", "ess_events", "ess_on_2d")
+] + [
+    # Episode tables at the fault process's cap fill half the block VMEM;
+    # 8192 racks take several rack blocks, whose rows are double-buffered.
+    pytest.param("ess_events", n, flt.MAX_EPISODES, id=f"ess_events-{n}-max_episodes")
+    for n in (R, 4000, 8192)
+] + [
+    # An explicit episode count past the cap: the tables alone fill more
+    # than three quarters of the budget, so the kernel asks for more VMEM.
+    pytest.param(
+        "ess_events", 8192, 2 * flt.MAX_EPISODES, id="ess_events-8192-2x_max_episodes"),
+])
+def test_megakernel_compiles_for_v5e(one_chip, variant, n_racks, n_events):
+    args, kw = _megakernel_args(variant, one_chip, n_racks, n_events)
     compiled = pdu_health.pdu_health_sim.lower(*args, **kw).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     # Two (k, R) f32 outputs at least; the program fits one chip's 16 GB.
-    assert mem.output_size_in_bytes >= 2 * K * R * 4
+    assert mem.output_size_in_bytes >= 2 * K * n_racks * 4
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
