@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compliance, fleet, pdu
+from repro.core import compliance, fleet, pdu, profiling as _prof
 from repro.sharding import rules
 from repro.utils import pytree_dataclass, static_field
 
@@ -108,7 +108,8 @@ def poi_response(
                 f2 = f + a * (-d - damp * f)
                 return f2, f2
 
-            _, fdev = jax.lax.scan(step, jnp.float32(0.0), dp_sys)
+            with _prof.scope("swing"):
+                _, fdev = jax.lax.scan(step, jnp.float32(0.0), dp_sys)
             freq = fdev * jnp.float32(poi.f0_hz)
             volt = -jnp.float32(poi.v_sens) * dp
             return POIResponse(
@@ -406,8 +407,9 @@ def _poi_fold(bank, mbank, chunk, n_full, rem, dt):
             if n_full:
                 def body(po, xs):
                     cr, cg = xs
-                    return _poi_observers_update(
-                        po, bank, mbank, cr, cg, dt), None
+                    with _prof.scope("poi"):
+                        return _poi_observers_update(
+                            po, bank, mbank, cr, cg, dt), None
 
                 po, _ = jax.lax.scan(
                     body, po,
@@ -415,10 +417,11 @@ def _poi_fold(bank, mbank, chunk, n_full, rem, dt):
                      pg[: n_full * chunk].reshape(n_full, chunk)),
                 )
             if rem:
-                po = _poi_observers_update(
-                    po, bank, mbank,
-                    pr[n_full * chunk:], pg[n_full * chunk:], dt,
-                )
+                with _prof.scope("poi"):
+                    po = _poi_observers_update(
+                        po, bank, mbank,
+                        pr[n_full * chunk:], pg[n_full * chunk:], dt,
+                    )
             return po
 
         return run
@@ -593,10 +596,11 @@ def _region_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, bank, mbank):
                 st2, ch = fleet._condition_chunk(
                     cfg, scen, st, t0, n, k=k, qp_iters=qp_iters)
                 obs2 = fleet._observers_update(obs, bank, ch, cfg.sample_dt)
-                pr = jax.lax.psum(wl * ch.campus_rack, caxis)
-                pg = jax.lax.psum(wl * ch.campus_grid, caxis)
-                po2 = _poi_observers_update(
-                    po, bank, mbank, pr, pg, cfg.sample_dt)
+                with _prof.scope("poi"):
+                    pr = jax.lax.psum(wl * ch.campus_rack, caxis)
+                    pg = jax.lax.psum(wl * ch.campus_grid, caxis)
+                    po2 = _poi_observers_update(
+                        po, bank, mbank, pr, pg, cfg.sample_dt)
                 return st2, obs2, po2, ch, pr, pg
 
             parts, prs, pgs, worst, htrace, strace = [], [], [], [], [], []
@@ -689,67 +693,72 @@ def condition_region_sharded(
             "campuses; exactly one campus per shard keeps the psum "
             "reduction order equal to the sequential left-to-right sum "
             "(the bitwise-parity contract)")
-    for scen in reg.campuses:
-        fleet._check_scenario_rate(scen, cfg)
-        fleet._check_scenario_faults(scen, cfg)
-    k, chunk, start, stop, t_total, n_full, rem, n_ctrl = _chunk_geometry(
-        cfg, reg, chunk_intervals, start_sample, stop_sample)
+    with _prof.span("prepare"):
+        for scen in reg.campuses:
+            fleet._check_scenario_rate(scen, cfg)
+            fleet._check_scenario_faults(scen, cfg)
+        k, chunk, start, stop, t_total, n_full, rem, n_ctrl = _chunk_geometry(
+            cfg, reg, chunk_intervals, start_sample, stop_sample)
 
-    states = (None,) * C if states is None else tuple(states)
-    if len(states) != C:
-        raise ValueError(f"{len(states)} states for {C} campuses")
-    if any(s is None for s in states):
-        if not all(s is None for s in states):
-            raise ValueError(
-                "per-campus resume states must be all-None (fresh start) "
-                "or all present")
+        states = (None,) * C if states is None else tuple(states)
+        if len(states) != C:
+            raise ValueError(f"{len(states)} states for {C} campuses")
+        if any(s is None for s in states):
+            if not all(s is None for s in states):
+                raise ValueError(
+                    "per-campus resume states must be all-None (fresh start) "
+                    "or all present")
 
-        def init_one(scen):
-            r0 = SC.render(scen, start, 1)[0]
-            if r0.ndim == 0:
-                r0 = r0[None]
-            return pdu.init_state(cfg, r0, soc0=soc0)
+            def init_one(scen):
+                r0 = SC.render(scen, start, 1)[0]
+                if r0.ndim == 0:
+                    r0 = r0[None]
+                return pdu.init_state(cfg, r0, soc0=soc0)
 
-        states = tuple(init_one(scen) for scen in reg.campuses)
-    # Stacking copies, so the donated stacked state never aliases the
-    # caller's checkpoint.
-    st_s = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
-    scen_s = _stack_campuses(reg)
+            states = tuple(init_one(scen) for scen in reg.campuses)
+        # Stacking copies, so the donated stacked state never aliases the
+        # caller's checkpoint.
+        st_s = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
+        scen_s = _stack_campuses(reg)
 
-    bank = fleet._make_bank(grid_spec, cfg, t_total)
-    mbank = mode_bank(t_total, cfg.sample_dt, reg.bands)
-    run = _region_engine(
-        cfg, qp_iters, chunk, k, n_full, rem, mesh, bank, mbank)
-    st_f, camp, obs_s, poi_rack, poi_grid, po = run(
-        scen_s, st_s, reg.weights, jnp.asarray(start, jnp.int32))
+        bank = fleet._make_bank(grid_spec, cfg, t_total)
+        mbank = mode_bank(t_total, cfg.sample_dt, reg.bands)
+        run = _region_engine(
+            cfg, qp_iters, chunk, k, n_full, rem, mesh, bank, mbank)
+    with _prof.span("engine"):
+        st_f, camp, obs_s, poi_rack, poi_grid, po = run(
+            scen_s, st_s, reg.weights, jnp.asarray(start, jnp.int32))
 
-    take = lambda t, c: jax.tree_util.tree_map(lambda x: x[c], t)
-    campus_rack = camp.campus_rack[:, :t_total]
-    campus_grid = camp.campus_grid[:, :t_total]
-    soc_mean = camp.soc_mean[:, :n_ctrl]
-    ess_frac = camp.ess_online_frac[:, :n_ctrl]
-    per = [
-        fleet._finish_streaming(
-            cfg, grid_spec, take(st_f, c),
-            campus_rack[c], campus_grid[c], soc_mean[c],
-            camp.max_qp_residual[c], bank, take(obs_s, c),
-            camp.health[c], ess_frac[c], camp.safemode[c],
+    # Its own name, so idle under the region's slices, assembly and POI
+    # reports is told apart from the per-campus ``repro.finish`` inside.
+    with _prof.span("region_finish"):
+        take = lambda t, c: jax.tree_util.tree_map(lambda x: x[c], t)
+        campus_rack = camp.campus_rack[:, :t_total]
+        campus_grid = camp.campus_grid[:, :t_total]
+        soc_mean = camp.soc_mean[:, :n_ctrl]
+        ess_frac = camp.ess_online_frac[:, :n_ctrl]
+        per = [
+            fleet._finish_streaming(
+                cfg, grid_spec, take(st_f, c),
+                campus_rack[c], campus_grid[c], soc_mean[c],
+                camp.max_qp_residual[c], bank, take(obs_s, c),
+                camp.health[c], ess_frac[c], camp.safemode[c],
+            )
+            for c in range(C)
+        ]
+        return _assemble_region_result(
+            cfg, reg, grid_spec, per,
+            campus_rack=campus_rack,
+            campus_grid=campus_grid,
+            soc_mean=soc_mean,
+            health_trace=camp.health,
+            ess_frac=ess_frac,
+            max_qp=jnp.max(camp.max_qp_residual),
+            poi_rack=poi_rack[:t_total],
+            poi_grid=poi_grid[:t_total],
+            po=po, bank=bank, mbank=mbank,
+            sm_trace=camp.safemode,
         )
-        for c in range(C)
-    ]
-    return _assemble_region_result(
-        cfg, reg, grid_spec, per,
-        campus_rack=campus_rack,
-        campus_grid=campus_grid,
-        soc_mean=soc_mean,
-        health_trace=camp.health,
-        ess_frac=ess_frac,
-        max_qp=jnp.max(camp.max_qp_residual),
-        poi_rack=poi_rack[:t_total],
-        poi_grid=poi_grid[:t_total],
-        po=po, bank=bank, mbank=mbank,
-        sm_trace=camp.safemode,
-    )
 
 
 def condition_region(
