@@ -34,12 +34,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import compliance, fleet, pdu, profiling as _prof
 from repro.sharding import rules
@@ -563,10 +564,44 @@ def condition_region_sequential(
     )
 
 
-def _stack_campuses(reg: GridRegion):
+# A campus's state or scenario with the campus axis added, on the device it
+# is on.  The output is a fresh buffer, so the engine's donation of the
+# stacked state never takes the caller's (a checkpoint, or the state a
+# harness restarts from).
+_lift = jax.jit(lambda t: jax.tree_util.tree_map(lambda x: x[None], t))
+
+
+def _stack_states(states, mesh):
+    """The per-campus trees as one tree sharded ``P("campus")`` over
+    ``mesh``: each lifted on its own campus's device (moved there first
+    unless it already is), the pieces joined on the host.  No program spans
+    two campuses."""
+    sharding = NamedSharding(mesh, P("campus"))
+    per_dev = []  # one lifted tree per device of the mesh, campus-major
+    for st, row in zip(states, rules.campus_rows(mesh)):
+        piece = _lift(jax.device_put(st, row[0]))
+        per_dev += [piece] + [jax.device_put(piece, d) for d in row[1:]]
+    return jax.tree_util.tree_map(
+        lambda *xs: jax.make_array_from_single_device_arrays(
+            (len(states),) + xs[0].shape[1:], sharding, list(xs)),
+        *per_dev)
+
+
+# The stacked scenarios of the last region conditioned on each mesh, beside
+# a weak reference to that region: a region's campuses do not change from
+# call to call, so its calls after the first reuse the stack.
+_SCENARIO_STACKS: dict = {}
+
+
+def _stack_campuses(reg: GridRegion, mesh):
+    """The campuses' scenarios as one campus-sharded scenario, each campus
+    on its own devices (``_stack_states``), stacked once per region and
+    mesh."""
+    held, scen_s = _SCENARIO_STACKS.get(mesh, (None, None))
+    if held is not None and held() is reg:
+        return scen_s
     try:
-        return jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *reg.campuses)
+        scen_s = _stack_states(reg.campuses, mesh)
     except (ValueError, TypeError) as e:
         raise ValueError(
             "the sharded region engine stacks campuses into one batched "
@@ -574,6 +609,32 @@ def _stack_campuses(reg: GridRegion):
             "(statics, rack count, fault-schedule shape); heterogeneous "
             f"regions run the sequential engine (mesh=None): {e}"
         ) from None
+    _SCENARIO_STACKS[mesh] = (weakref.ref(reg), scen_s)
+    return scen_s
+
+
+def _split_campuses(tree, devices):
+    """Campus ``c``'s shards of the campus-sharded arrays of ``tree``, as
+    single-device arrays on ``devices[c]``: read from the shards each
+    device already holds, so nothing is gathered or copied."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    by_dev = [{s.device: s.data for s in x.addressable_shards} for x in leaves]
+    return [jax.tree_util.tree_unflatten(treedef, [b[d] for b in by_dev])
+            for d in devices]
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _campus_view(tree, t_total, n_ctrl):
+    """One campus's (state, observers, aggregates) shard with the campus
+    axis dropped and the padded traces cut to the window; runs on the
+    campus's own device."""
+    st, obs, camp = jax.tree_util.tree_map(lambda x: x[0], tree)
+    return st, obs, camp._replace(
+        campus_rack=camp.campus_rack[:t_total],
+        campus_grid=camp.campus_grid[:t_total],
+        soc_mean=camp.soc_mean[:n_ctrl],
+        ess_online_frac=camp.ess_online_frac[:n_ctrl],
+    )
 
 
 def _region_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, bank, mbank):
@@ -678,7 +739,10 @@ def condition_region_sharded(
     conditions the whole region, with the POI reduced by in-scan ``psum``.
     Requires a mesh with a "campus" axis of exactly ``n_campuses`` shards
     (``rules.region_mesh``) and stackable campuses; bitwise equal to
-    ``condition_region_sequential`` on campus and POI aggregates."""
+    ``condition_region_sequential`` on campus and POI aggregates.  Campus
+    ``c``'s own results (``per_campus[c]``, ``state[c]``) live on the first
+    device of its mesh row (``rules.campus_rows``), and a resume state may
+    sit anywhere: each is moved to its campus's device once."""
     from repro.power import scenario as SC
 
     C = reg.n_campuses
@@ -716,10 +780,9 @@ def condition_region_sharded(
                 return pdu.init_state(cfg, r0, soc0=soc0)
 
             states = tuple(init_one(scen) for scen in reg.campuses)
-        # Stacking copies, so the donated stacked state never aliases the
-        # caller's checkpoint.
-        st_s = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states)
-        scen_s = _stack_campuses(reg)
+        # Campus c's leaves stay on row c of the mesh, both ways.
+        scen_s = _stack_campuses(reg, mesh)
+        st_s = _stack_states(states, mesh)
 
         bank = fleet._make_bank(grid_spec, cfg, t_total)
         mbank = mode_bank(t_total, cfg.sample_dt, reg.bands)
@@ -732,19 +795,22 @@ def condition_region_sharded(
     # Its own name, so idle under the region's slices, assembly and POI
     # reports is told apart from the per-campus ``repro.finish`` inside.
     with _prof.span("region_finish"):
-        take = lambda t, c: jax.tree_util.tree_map(lambda x: x[c], t)
         campus_rack = camp.campus_rack[:, :t_total]
         campus_grid = camp.campus_grid[:, :t_total]
         soc_mean = camp.soc_mean[:, :n_ctrl]
         ess_frac = camp.ess_online_frac[:, :n_ctrl]
+        views = [
+            _campus_view(t, t_total, n_ctrl)
+            for t in _split_campuses(
+                (st_f, obs_s, camp), rules.campus_rows(mesh)[:, 0])
+        ]
         per = [
             fleet._finish_streaming(
-                cfg, grid_spec, take(st_f, c),
-                campus_rack[c], campus_grid[c], soc_mean[c],
-                camp.max_qp_residual[c], bank, take(obs_s, c),
-                camp.health[c], ess_frac[c], camp.safemode[c],
+                cfg, grid_spec, st, cp.campus_rack, cp.campus_grid,
+                cp.soc_mean, cp.max_qp_residual, bank, obs, cp.health,
+                cp.ess_online_frac, cp.safemode,
             )
-            for c in range(C)
+            for st, obs, cp in views
         ]
         return _assemble_region_result(
             cfg, reg, grid_spec, per,

@@ -286,6 +286,14 @@ def region_mesh(
     )
 
 
+def campus_rows(mesh: Mesh) -> np.ndarray:
+    """(n_campuses, devices per campus) array of ``mesh``'s devices: row
+    ``c`` holds every device that keeps campus ``c``'s shard of an array
+    sharded ``P("campus")``, the campus's own device first."""
+    devs = np.moveaxis(mesh.devices, mesh.axis_names.index("campus"), 0)
+    return devs.reshape(devs.shape[0], -1)
+
+
 def constrain_activations(x: jax.Array) -> jax.Array:
     """Standard (B, T, D) activation constraint: batch on ("pod","data")."""
     return maybe_constrain(x, ("pod", "data"))

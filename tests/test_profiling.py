@@ -74,7 +74,7 @@ def _region_engine_paths(n_campuses: int, mesh) -> tuple:
     state = jax.tree_util.tree_map(
         lambda *xs: jnp.stack(xs),
         *(pdu.init_state(cfg, jnp.ones((4,))) for _ in range(n_campuses)))
-    text = run.lower(grid._stack_campuses(reg), state, reg.weights,
+    text = run.lower(grid._stack_campuses(reg, mesh), state, reg.weights,
                      jnp.asarray(0, jnp.int32)).compile().as_text()
     fold = grid._poi_fold(bank, mbank, chunk, n_full, rem, cfg.sample_dt)
     trace = jnp.ones((reg.total_samples,), jnp.float32)
