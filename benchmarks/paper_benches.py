@@ -275,46 +275,6 @@ def bench_controller_throughput():
     )
 
 
-def bench_fleet_streaming():
-    """Streaming campus engine: 1024 racks conditioned in time chunks with
-    donated state and on-the-fly chunk synthesis — live HBM stays
-    O(chunk x racks) instead of 2x the (T, R) campus trace."""
-    n_racks = _q(1024, 64)
-    sp = trace.TestbenchSpec(duration_s=60.0, sample_hz=200.0)
-    t1, dt = trace.testbench_trace(sp, jax.random.key(7))
-    offsets = jax.random.randint(jax.random.key(13), (n_racks,), 0, 800)
-    cfg = pdu.make_pdu(sample_dt=dt)
-    spec = compliance.GridSpec.create()
-    t_total = t1.shape[0]
-
-    def provider(t0, n):
-        # synthesize the (n, R) chunk from the base trace + per-rack offsets
-        idx = (jnp.arange(t0, t0 + n)[:, None] - offsets[None, :]) % t_total
-        return t1[idx]
-
-    import time as _time
-
-    fleet.condition_fleet_streaming(  # compile all chunk shapes
-        cfg, provider, spec, qp_iters=30, chunk_intervals=4, total_samples=t_total
-    )
-    t0 = _time.perf_counter()
-    res = fleet.condition_fleet_streaming(
-        cfg, provider, spec, qp_iters=30, chunk_intervals=4, total_samples=t_total
-    )
-    jax.block_until_ready(res.campus_grid)
-    us = (_time.perf_counter() - t0) * 1e6
-    UNITS["fleet_streaming_1024racks"] = dict(racks=n_racks, samples=t_total * n_racks)
-    rg = float(compliance.max_abs_ramp(res.campus_grid, dt))
-    k = int(round(float(cfg.controller.dt) / dt))
-    live_mb = 4 * k * 4 * n_racks / 1e6  # chunk_intervals * k samples x R x f32
-    full_mb = 2 * t_total * n_racks * 4 / 1e6
-    return "fleet_streaming_1024racks", us, (
-        f"campus_ramp={rg:.4f}/s ok={bool(res.report_grid.ramp_ok)} "
-        f"us_per_rack={us / n_racks:.0f} qp_resid={float(res.max_qp_residual):.2e} "
-        f"live_chunk={live_mb:.0f}MB vs one-shot {full_mb:.0f}MB"
-    )
-
-
 def bench_scenario_render():
     """Scenario-engine synthesis throughput: host-materialized one-shot
     (T, R) render vs on-device chunked rendering (the streaming conditioner's
@@ -390,48 +350,27 @@ def bench_mixed_campus():
     model-derived workloads + an inference-diurnal block, staggered job
     starts/stops, and a mid-trace fault cascade — conditioned end-to-end by
     the scanned engine (render + chunk loop fused into ONE dispatch, no
-    (T, R) host materialization ever).  The per-chunk host-loop engine runs
-    once for the derived speedup; in ``--quick`` mode the two are asserted
-    to agree (campus aggregates bitwise where XLA fusion allows, <= a few
-    ulp on the filter chain), so the CI smoke run doubles as an
-    engine-equivalence check."""
+    (T, R) host materialization ever).  The engine-equivalence contract
+    against the one-shot reference is pinned by the tier-1 tests."""
     n_racks = _q(1024, 64)
     duration = _q(88.0, 30.0)
     hz = 200.0
     s = mixed_campus_scenario(n_racks, duration, hz)
     cfg = pdu.make_pdu(sample_dt=1.0 / hz)
     spec = compliance.GridSpec.create()
-    run = lambda engine: fleet.condition_scenario_streaming(
-        cfg, s, spec, engine=engine, qp_iters=30, chunk_intervals=4
+    run = lambda: fleet.condition(
+        s, cfg, spec, qp_iters=30, stream=fleet.StreamOptions(chunk_intervals=4)
     )
-    run("scanned")  # compile
-    us, res = _best_of(lambda: run("scanned"), lambda r: r.campus_grid)
+    run()  # compile
+    us, res = _best_of(run, lambda r: r.campus_grid)
     UNITS["mixed_campus_fleet"] = dict(racks=n_racks, samples=s.total_samples * n_racks)
-
-    host = run("host")  # warm the host-loop engine
-    t0 = time.perf_counter()
-    host = run("host")
-    jax.block_until_ready(host.campus_grid)
-    us_host = (time.perf_counter() - t0) * 1e6
-    if QUICK:
-        np.testing.assert_array_equal(
-            np.asarray(res.campus_rack), np.asarray(host.campus_rack)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(res.soc_mean), np.asarray(host.soc_mean)
-        )
-        np.testing.assert_allclose(
-            np.asarray(res.campus_grid), np.asarray(host.campus_grid), atol=1e-6
-        )
 
     rg = float(res.report_grid.max_ramp)
     LAST_US["mixed_campus_fleet"] = us
     return "mixed_campus_fleet", us, (
         f"racks={n_racks} workloads=5 campus_ramp={rg:.4f}/s "
         f"ok={bool(res.report_grid.ramp_ok)} raw_ok={bool(res.report_rack.ramp_ok)} "
-        f"us_per_rack={us / n_racks:.0f} qp_resid={float(res.max_qp_residual):.2e} "
-        f"host_loop_us={us_host:.0f} ({us_host / us:.2f}x scanned)"
-        + (" engines_agree=True" if QUICK else "")
+        f"us_per_rack={us / n_racks:.0f} qp_resid={float(res.max_qp_residual):.2e}"
     )
 
 
@@ -449,8 +388,8 @@ def bench_mixed_campus_health():
     s = mixed_campus_scenario(n_racks, duration, hz)
     cfg = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True)
     spec = compliance.GridSpec.create()
-    run = lambda: fleet.condition_scenario_streaming(
-        cfg, s, spec, qp_iters=30, chunk_intervals=4
+    run = lambda: fleet.condition(
+        s, cfg, spec, qp_iters=30, stream=fleet.StreamOptions(chunk_intervals=4)
     )
     run()  # compile
     us, res = _best_of(run, lambda r: r.campus_grid)
@@ -514,8 +453,8 @@ def bench_mixed_campus_safemode():
     cfg_off = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True)
     cfg_on = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True, safemode=True)
     spec = compliance.GridSpec.create()
-    run = lambda c: fleet.condition_scenario_streaming(
-        c, s, spec, qp_iters=30, chunk_intervals=4
+    run = lambda c: fleet.condition(
+        s, c, spec, qp_iters=30, stream=fleet.StreamOptions(chunk_intervals=4)
     )
     run(cfg_off), run(cfg_on)  # compile both
     # The two configs are timed INTERLEAVED (not vs the earlier
@@ -607,8 +546,8 @@ def bench_mixed_campus_faulty():
     with the availability mask derived in-jit from the schedule's episode
     table.  Asserts the campus still meets the ramp spec with a third of
     the conditioning fleet dark (the honest claim rides in
-    min_online_frac), and in ``--quick`` mode cross-checks the host-loop
-    engine for degraded-path equivalence.
+    min_online_frac), and in ``--quick`` mode cross-checks the megakernel
+    against its jnp reference on one interval of this campus.
 
     The campus renders with ``edge_pad='clamp'`` — the legacy zero-padded
     smoothing window fabricates a fleet-synchronized half-power decay at
@@ -621,25 +560,14 @@ def bench_mixed_campus_faulty():
     sched = s.faults
     cfg = pdu.make_pdu(sample_dt=1.0 / hz, degraded_mode=True)
     spec = compliance.GridSpec.create()
-    run = lambda engine: fleet.condition_scenario_streaming(
-        cfg, s, spec, engine=engine, qp_iters=30, chunk_intervals=4
+    run = lambda: fleet.condition(
+        s, cfg, spec, qp_iters=30, stream=fleet.StreamOptions(chunk_intervals=4)
     )
-    run("scanned")  # compile
-    us, res = _best_of(lambda: run("scanned"), lambda r: r.campus_grid)
+    run()  # compile
+    us, res = _best_of(run, lambda r: r.campus_grid)
     UNITS["mixed_campus_faulty"] = dict(racks=n_racks, samples=s.total_samples * n_racks)
 
     if QUICK:
-        host = run("host")
-        np.testing.assert_array_equal(
-            np.asarray(res.campus_rack), np.asarray(host.campus_rack)
-        )
-        np.testing.assert_array_equal(
-            np.asarray(res.ess_online_frac), np.asarray(host.ess_online_frac)
-        )
-        np.testing.assert_allclose(
-            np.asarray(res.campus_grid), np.asarray(host.campus_grid), atol=1e-6
-        )
-
         # Megakernel-vs-ref ride-along on the fused weight operand
         # (mirrors bench_mixed_campus_health's QUICK block): one mid-trace
         # controller interval of THIS campus, with the ESS availability
@@ -694,7 +622,7 @@ def bench_mixed_campus_faulty():
         f"campus_ramp={float(res.report_grid.max_ramp):.4f}/s "
         f"ok={bool(res.report_grid.ramp_ok)} "
         f"overhead_vs_clean={overhead} us_per_rack={us / n_racks:.0f}"
-        + (" engines_agree=True megakernel_agrees=True" if QUICK else "")
+        + (" megakernel_agrees=True" if QUICK else "")
     )
 
 
@@ -764,7 +692,6 @@ ALL = [
     bench_appendixA_sizing,
     bench_controller_throughput,
     bench_fleet_scale,
-    bench_fleet_streaming,
     bench_scenario_render,
     bench_mixed_campus,
     bench_mixed_campus_health,

@@ -11,7 +11,7 @@ Phases (one chip):
    (k = 1000 samples, 1024 racks): the interval megakernel in its three
    engine variants and the batched ADMM loop of the controller QP.
 3. The 1024-rack acceptance campus through ``fleet.condition``, scanned
-   engine against the host-loop engine.
+   engine against the one-shot reference engine.
 4. The operator loop (``ConditionerService``) on the faulted campus:
    three windows, checkpoint, restore into a new service, one more window,
    bitwise against the uninterrupted run.
@@ -55,8 +55,8 @@ REGION_HZ = 50.0
 QP_ITERS = 30
 GRID_TOL = 1e-5  # megakernel grid output and LC state (pdu_health.py docstring)
 ADMM_TOL = 2e-5  # batched ADMM iterates (tests/test_pdu_health_kernel.py)
-ENGINE_MAX_ULP = 4  # scanned vs host campus_rack / soc_mean
-ENGINE_GRID_TOL = 1e-6  # scanned vs host campus_grid (bench_mixed_campus)
+ENGINE_MAX_ULP = 4  # scanned vs oneshot campus_rack
+ENGINE_GRID_TOL = 1e-6  # scanned vs oneshot campus_grid
 REGION_TOL = 1e-5  # sharded vs sequential campus_grid, poi_grid, poi_freq_dev
 
 
@@ -274,49 +274,50 @@ def phase_kernels(size: Size, force: str | None = None) -> dict:
 
 def phase_campus(size: Size) -> dict:
     """The acceptance campus through ``fleet.condition``: scanned engine,
-    then the host-loop engine on the same campus."""
+    then the one-shot reference engine on the same campus."""
     s = _mixed_campus(size.racks, size.campus_s)
     cfg = pdu.make_pdu(sample_dt=1.0 / HZ, track_health=True)
     spec = compliance.GridSpec.create()
-    run = lambda engine: fleet.condition(
-        s, cfg, spec, engine=engine, qp_iters=QP_ITERS,
-        stream=fleet.StreamOptions(chunk_intervals=4))
+    runs = {
+        "scanned": lambda: fleet.condition(
+            s, cfg, spec, qp_iters=QP_ITERS,
+            stream=fleet.StreamOptions(chunk_intervals=4)),
+        "oneshot": lambda: fleet.condition(
+            s, cfg, spec, engine="oneshot", qp_iters=QP_ITERS),
+    }
     results = {}
-    for engine in ("scanned", "host"):
-        cold, _ = _timed(lambda: run(engine))
-        warm, results[engine] = _timed(lambda: run(engine))
+    for engine, run in runs.items():
+        cold, _ = _timed(run)
+        warm, results[engine] = _timed(run)
         _say(f"campus {engine} engine (one smoke run, not a benchmark): "
              f"racks={size.racks} samples={s.total_samples} first call "
              f"{cold:.3f}s, warm call {warm:.3f}s, compile ~{cold - warm:.3f}s")
-    scanned, host = results["scanned"], results["host"]
+    scanned, oneshot = results["scanned"], results["oneshot"]
 
     ep = cfg.ess_params
-    for engine, r in (("scanned", scanned), ("host", host)):
-        if not _all_finite((r.campus_rack, r.campus_grid, r.soc_mean,
-                            r.health_trace, r.state)):
+    if not _all_finite((scanned.soc_mean, scanned.health_trace, scanned.state)):
+        raise AssertionError("campus scanned: non-finite output")
+    soc = np.asarray(scanned.state.ess_state.soc)
+    sm = np.asarray(scanned.soc_mean)
+    lo, hi = float(ep.soc_safe_min), float(ep.soc_safe_max)
+    if not (np.all((soc >= lo) & (soc <= hi))
+            and np.all((sm >= lo) & (sm <= hi))):
+        raise AssertionError(f"campus scanned: SoC left [{lo}, {hi}]")
+    for engine, r in results.items():
+        if not _all_finite((r.campus_rack, r.campus_grid)):
             raise AssertionError(f"campus {engine}: non-finite output")
-        soc = np.asarray(r.state.ess_state.soc)
-        sm = np.asarray(r.soc_mean)
-        lo, hi = float(ep.soc_safe_min), float(ep.soc_safe_max)
-        if not (np.all((soc >= lo) & (soc <= hi))
-                and np.all((sm >= lo) & (sm <= hi))):
-            raise AssertionError(f"campus {engine}: SoC left [{lo}, {hi}]")
         if not bool(r.report_grid.ramp_ok):
             raise AssertionError(
                 f"campus {engine}: conditioned campus fails the ramp spec "
                 f"(max ramp {float(r.report_grid.max_ramp):.4f}/s)")
 
-    out = {}
-    for nm in ("campus_rack", "soc_mean"):
-        a, b = np.asarray(getattr(scanned, nm)), np.asarray(getattr(host, nm))
-        out[nm] = _max_abs(a, b)
-        np.testing.assert_array_max_ulp(a, b, maxulp=ENGINE_MAX_ULP)
-    out["campus_grid"] = _max_abs(scanned.campus_grid, host.campus_grid)
-    _within("campus_grid scanned vs host", out["campus_grid"], ENGINE_GRID_TOL)
+    a, b = np.asarray(scanned.campus_rack), np.asarray(oneshot.campus_rack)
+    out = {"campus_rack": _max_abs(a, b)}
+    np.testing.assert_array_max_ulp(a, b, maxulp=ENGINE_MAX_ULP)
+    out["campus_grid"] = _max_abs(scanned.campus_grid, oneshot.campus_grid)
+    _within("campus_grid scanned vs oneshot", out["campus_grid"], ENGINE_GRID_TOL)
     _say(f"campus engines agree: campus_rack {out['campus_rack']:.3e}"
-         f"{' (bitwise)' if _bitwise(scanned.campus_rack, host.campus_rack) else ''}"
-         f", soc_mean {out['soc_mean']:.3e}"
-         f"{' (bitwise)' if _bitwise(scanned.soc_mean, host.soc_mean) else ''}"
+         f"{' (bitwise)' if _bitwise(a, b) else ''}"
          f", campus_grid {out['campus_grid']:.3e}; ramp_ok=True max_ramp="
          f"{float(scanned.report_grid.max_ramp):.4f}/s raw_ramp_ok="
          f"{bool(scanned.report_rack.ramp_ok)} "

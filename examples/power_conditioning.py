@@ -108,8 +108,8 @@ def mixed_campus():
     )
     cfg = pdu.make_pdu(sample_dt=1.0 / hz, track_health=True)
     spec = compliance.GridSpec.create()
-    res = fleet.condition_scenario_streaming(cfg, scen, spec, qp_iters=30,
-                                             chunk_intervals=4)
+    res = fleet.condition(scen, cfg, spec, qp_iters=30,
+                          stream=fleet.StreamOptions(chunk_intervals=4))
     print(f"[Campus] 64 racks x {{{', '.join(archs)}, inference-diurnal}}: "
           f"raw ramp {float(res.report_rack.max_ramp):.2f}/s "
           f"(ok={bool(res.report_rack.ramp_ok)}) -> conditioned "

@@ -10,13 +10,13 @@ This module simulates heterogeneous fleets — per-rack phase offsets
 with the rack dimension vectorized (racks ride in the trailing axis of
 every core function, which the Pallas kernels map onto the 128-wide lane
 dimension).  For very large fleets the rack axis can be sharded over the
-same device mesh the trainer uses (`shard_racks`).
+same device mesh the trainer uses (the ``mesh`` argument of ``condition``).
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Callable, NamedTuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +24,7 @@ import numpy as np
 
 from repro.core import compliance, health as hlt, pdu, profiling as _prof, \
     safemode as smode
-from repro.sharding.rules import shard_racks, shard_racks_in_jit  # noqa: F401
-# (mesh utilities live in ``sharding.rules`` now; re-exported here for
-# compatibility — ``fleet.shard_racks`` keeps working.)
+from repro.sharding.rules import shard_racks_in_jit
 
 
 def synchronous_aggregate(rack_power: jax.Array, n_racks: int) -> jax.Array:
@@ -86,7 +84,7 @@ class ConditioningResult(NamedTuple):
 
     Optional fields are ``None`` when the producing engine does not track
     them: the one-shot engine has no streaming state or observers, the
-    streaming engines never materialize per-rack grid traces, and the POI /
+    scanned engine never materializes per-rack grid traces, and the POI /
     per-campus fields exist only for grid regions (``core.grid``, where the
     campus aggregates gain a leading ``(C,)`` campus axis).
     """
@@ -101,7 +99,7 @@ class ConditioningResult(NamedTuple):
     health: hlt.HealthReport = None
     # --- one-shot engine extras
     grid_traces: jax.Array = None  # (T, R) conditioned per-rack
-    # --- streaming engine extras
+    # --- scanned engine extras
     soc_mean: jax.Array = None  # (n_ctrl,) fleet-mean SoC per interval
     state: pdu.PDUState = None  # final PDU state (the stream can resume);
     #   a grid region carries a tuple of per-campus states instead.
@@ -122,7 +120,7 @@ class ConditioningResult(NamedTuple):
     poi_volt_dev: jax.Array = None  # (T,) first-order voltage deviation [pu]
     per_campus: tuple = None  # per-campus ConditioningResults
     weights: jax.Array = None  # (C,) campus POI weights
-    # --- observability handles (streaming engines) backing ``.report()``
+    # --- observability handles (scanned engine) backing ``.report()``
     grid_spec: compliance.GridSpec = None
     bank: compliance.SpectrumBank = None
     observers: "_Observers" = None
@@ -189,14 +187,7 @@ class ConditioningResult(NamedTuple):
         return out
 
 
-# Deprecated aliases: every engine returns ``ConditioningResult`` now, with
-# the former FleetResult / StreamingFleetResult fields as a subset.  New
-# code should name ``ConditioningResult`` (or just use the facade).
-FleetResult = ConditioningResult
-StreamingFleetResult = ConditioningResult
-
-
-def _condition_fleet_impl(
+def _condition_oneshot_impl(
     cfg: pdu.PDUConfig,
     traces: jax.Array,  # (T, R) per-unit rack traces
     grid_spec: compliance.GridSpec,
@@ -260,7 +251,7 @@ def _health_params(cfg: pdu.PDUConfig) -> hlt.HealthParams:
 
 
 class _Observers(NamedTuple):
-    """Streaming compliance state folded inside the engines' jitted steps:
+    """Streaming compliance state folded inside the chunk scan:
     reports come from these, not from re-diffing/FFT-ing materialized
     campus arrays — so compliance is available online however long the
     stream runs (and the cross-chunk boundary ramp is never dropped)."""
@@ -300,24 +291,10 @@ def _make_bank(
     )
 
 
-class _CampusAccum(NamedTuple):
-    """Preallocated on-device output buffers for the host-loop engine."""
-
-    campus_rack: jax.Array  # (n_chunks * chunk,)
-    campus_grid: jax.Array  # (n_chunks * chunk,)
-    soc_mean: jax.Array  # (n_chunks * chunk_intervals,)
-    worst: jax.Array  # () running max QP primal residual
-    health_trace: jax.Array  # (n_chunks, 3) fleet wear snapshot per chunk
-    ess_frac: jax.Array  # (n_chunks * chunk_intervals,) online fraction
-    sm_trace: jax.Array  # (n_chunks, 6) safe-mode snapshot per chunk
-    obs: _Observers  # streaming compliance state
-
-
-# The streaming engines close their jitted steps over a concrete PDUConfig
+# The engines close their jitted programs over a concrete PDUConfig
 # (pdu.condition bakes config scalars into the kernel via float(...)), so
 # the jit wrapper must be cached *outside* the engine call or every
-# invocation would retrace and recompile from scratch — which is exactly
-# the per-call recompile the pre-scanned benches were paying.  PDUConfig
+# invocation would retrace and recompile from scratch.  PDUConfig
 # leaves are config scalars, so a value-based key is exact; anything
 # non-scalar falls back to an uncached (per-call) jit.
 _ENGINE_CACHE: dict = {}
@@ -348,11 +325,10 @@ def _cached_engine(key, build):
 def make_condition_step(cfg: pdu.PDUConfig, *, qp_iters: int = 30, donate: bool = True):
     """A cached, jitted ``(state, trace) -> (grid, state, telemetry)`` step.
 
-    The single-chunk building block of the streaming engines, exposed for
-    callers (e.g. ``power.integration.PowerSim``) that condition a stream
-    of same-shaped chunks: the returned function is cached per config, so
-    repeated construction never retraces, and the carried ``PDUState`` is
-    donated between chunks.
+    For callers (e.g. ``power.integration.PowerSim``) that walk a stream
+    of same-shaped chunks themselves: the returned function is cached per
+    config, so repeated construction never retraces, and the carried
+    ``PDUState`` is donated between chunks.
     """
 
     def build():
@@ -362,83 +338,6 @@ def make_condition_step(cfg: pdu.PDUConfig, *, qp_iters: int = 30, donate: bool 
         return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     return _cached_engine(_engine_key(cfg, "condition_step", qp_iters, donate), build)
-
-
-def _host_stream_step(cfg, qp_iters, chunk, n_int, mesh, rack_axis, bank,
-                      use_faults=False, fault_edge=1):
-    """Cached jitted host-loop chunk step: condition + accumulate on-device.
-
-    Campus aggregates are written into the preallocated ``_CampusAccum``
-    buffers with ``dynamic_update_slice`` (the chunk index rides in as a
-    traced scalar, so one compilation serves every full chunk; a ragged
-    tail adds one more) and the worst QP residual is folded as a running
-    max — no host-side list appends, ``jnp.concatenate``, or growing lazy
-    ``jnp.maximum`` chains.  Write offsets use the *full* chunk geometry
-    (``chunk`` samples / ``n_int`` intervals), not the possibly-shorter
-    incoming block, so the ragged tail lands at the right position.
-
-    With ``use_faults`` the degraded step carries the fault schedule itself
-    (a small episode-table pytree) instead of streamed per-chunk mask/weight
-    blocks; the chunk's absolute start sample is ``c_idx * chunk`` in-jit,
-    so one compilation still serves every full chunk.
-    """
-
-    def build():
-        def step_impl(st, acc, tr, c_idx, on, wt, fl):
-            if mesh is not None:
-                tr = shard_racks_in_jit(tr, mesh, rack_axis)
-            st2, ch = pdu.condition_campus(
-                cfg, st, tr, qp_iters=qp_iters, ess_online=on, ess_weight=wt,
-                faults=fl, chunk_start=c_idx * chunk, fault_edge=fault_edge,
-            )
-            acc2 = _CampusAccum(
-                campus_rack=jax.lax.dynamic_update_slice(
-                    acc.campus_rack, ch.campus_rack, (c_idx * chunk,)
-                ),
-                campus_grid=jax.lax.dynamic_update_slice(
-                    acc.campus_grid, ch.campus_grid, (c_idx * chunk,)
-                ),
-                soc_mean=jax.lax.dynamic_update_slice(
-                    acc.soc_mean, ch.soc_mean, (c_idx * n_int,)
-                ),
-                worst=jnp.maximum(acc.worst, ch.max_qp_residual),
-                health_trace=jax.lax.dynamic_update_slice(
-                    acc.health_trace, ch.health[None], (c_idx, 0)
-                ),
-                ess_frac=jax.lax.dynamic_update_slice(
-                    acc.ess_frac, ch.ess_online_frac, (c_idx * n_int,)
-                ),
-                sm_trace=jax.lax.dynamic_update_slice(
-                    acc.sm_trace, ch.safemode[None], (c_idx, 0)
-                ),
-                obs=_observers_update(acc.obs, bank, ch, cfg.sample_dt),
-            )
-            return st2, acc2
-
-        if cfg.degraded_mode and use_faults:
-            # Fault-schedule variant: the schedule rides in as a traced
-            # pytree and availability renders inside the conditioning scan.
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def step(st, acc, tr, c_idx, fl):
-                return step_impl(st, acc, tr, c_idx, None, None, fl)
-        elif cfg.degraded_mode:
-            # Degraded variant carries the chunk's availability-mask rows
-            # and (optionally) the per-sample hardware weight block.
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def step(st, acc, tr, c_idx, on, wt):
-                return step_impl(st, acc, tr, c_idx, on, wt, None)
-        else:
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def step(st, acc, tr, c_idx):
-                return step_impl(st, acc, tr, c_idx, None, None, None)
-
-        return step
-
-    return _cached_engine(
-        _engine_key(cfg, "host_stream", qp_iters, chunk, n_int, mesh, rack_axis,
-                    bank, use_faults, fault_edge),
-        build,
-    )
 
 
 def _finish_streaming(
@@ -482,163 +381,12 @@ def _finish_streaming(
         )
 
 
-def _condition_fleet_streaming_impl(
-    cfg: pdu.PDUConfig,
-    traces: jax.Array | Callable[[int, int], jax.Array],
-    grid_spec: compliance.GridSpec,
-    *,
-    soc0: float = 0.5,
-    qp_iters: int = 30,
-    chunk_intervals: int = 16,
-    total_samples: int | None = None,
-    mesh: jax.sharding.Mesh | None = None,
-    rack_axis: str = "data",
-    state: pdu.PDUState | None = None,
-    ess_online: jax.Array | None = None,
-    ess_weight: jax.Array | None = None,
-    faults=None,
-    fault_edge: int = 1,
-) -> ConditioningResult:
-    """Campus-scale conditioning in time chunks with bounded working set.
-
-    ``condition_fleet`` materializes the rack traces *and* the conditioned
-    grid waveform as full (T, R) arrays — 2x the campus trace in HBM, which
-    is what caps fleet size for hour-long traces.  This engine walks the
-    trace in chunks of ``chunk_intervals`` controller intervals, donates
-    the per-rack ``PDUState`` and the campus output buffers between chunks,
-    reduces each chunk to campus aggregates inside the jitted step (the
-    per-rack grid waveform never leaves the chunk), and carries the
-    controller's warm-started ADMM state across chunks via
-    ``PDUState.qp_warm`` — so at equal ``qp_iters`` the result is identical
-    to the one-shot ``condition_fleet`` call while live memory stays
-    O(chunk * R).  The default ``qp_iters=30`` assumes the warm-started
-    plan path, where 30 iterations match the seed cold-start path's
-    residual at 120 (EXPERIMENTS.md §Perf-4).
-
-    ``traces`` is either a (T, R) array or a chunk provider
-    ``f(start, length) -> (length, R)`` (with ``total_samples`` given) for
-    *external* sources — host-loaded or synthesized arrays the engine
-    cannot see inside its jit.  Declarative scenarios should prefer
-    ``condition_scenario_scanned``, which renders chunks inside one scanned
-    jit and dispatches once for the whole trace.  With ``mesh`` set, each
-    chunk is rack-sharded inside the jitted step
-    (``shard_racks_in_jit``); host-resident (non-jax) chunks are placed
-    with ``shard_racks`` first.  Passing ``state`` resumes a previous
-    stream (``soc0`` is then ignored); the stream must resume at a
-    controller-interval boundary, which every full chunk is.  A
-    caller-supplied ``state`` is copied before the (donated) step consumes
-    it, so the same checkpoint can seed several continuations.
-
-    ``ess_online`` (requires ``cfg.degraded_mode``) is the ESS availability
-    mask for the *whole* stream — ``(n_ctrl_total, R)`` per-interval rows
-    (sliced per chunk) or one ``(R,)`` mask applied throughout; semantics
-    as in ``pdu.condition``.  ``ess_weight`` is the optional per-sample
-    ``(T, R)`` hardware availability weight for the whole stream (sliced
-    per chunk by sample).  ``faults`` (mutually exclusive with both, and
-    preferred) is a ``power.faults.FaultSchedule`` for the whole stream:
-    availability renders inside the conditioning scan from the episode
-    boundary tables instead of streaming ``(T, R)`` weight blocks through
-    every chunk — bitwise-identical output at a fraction of the cost
-    (``fault_edge`` is the schedule's static edge ramp width in samples).
-    The scenario engines derive the right form from an attached fault
-    schedule automatically.
-    """
-    k = max(int(round(float(cfg.controller.dt) / cfg.sample_dt)), 1)
-    n_int = max(int(chunk_intervals), 1)
-    chunk = n_int * k
-    if callable(traces):
-        if total_samples is None:
-            raise ValueError("total_samples is required with a chunk provider")
-        provider, t_total = traces, int(total_samples)
-    else:
-        provider, t_total = (lambda t0, n: traces[t0 : t0 + n]), traces.shape[0]
-    n_chunks = -(-t_total // chunk)
-    n_ctrl = -(-t_total // k)
-    if ess_online is not None or ess_weight is not None:
-        if not cfg.degraded_mode:
-            raise ValueError(
-                "ess_online/ess_weight require a degraded-mode config "
-                "(make_pdu(..., degraded_mode=True))"
-            )
-        if faults is not None:
-            raise ValueError(
-                "faults is mutually exclusive with ess_online/ess_weight "
-                "(the schedule renders both internally)"
-            )
-        if ess_online is not None:
-            ess_online = jnp.asarray(ess_online, jnp.float32)
-        if ess_weight is not None:
-            ess_weight = jnp.asarray(ess_weight, jnp.float32)
-    if faults is not None and not cfg.degraded_mode:
-        raise ValueError(
-            "faults requires a degraded-mode config "
-            "(make_pdu(..., degraded_mode=True))"
-        )
-
-    if state is None:
-        state = pdu.init_state(cfg, provider(0, 1)[0], soc0=soc0)
-    else:
-        # The step donates its state argument; copy so the caller's
-        # checkpoint survives (and can seed several continuations).
-        state = jax.tree_util.tree_map(jnp.copy, state)
-
-    bank = _make_bank(grid_spec, cfg, t_total)
-    step = _host_stream_step(cfg, qp_iters, chunk, n_int, mesh, rack_axis, bank,
-                             use_faults=faults is not None,
-                             fault_edge=int(fault_edge))
-    acc = _CampusAccum(
-        campus_rack=jnp.zeros((n_chunks * chunk,), jnp.float32),
-        campus_grid=jnp.zeros((n_chunks * chunk,), jnp.float32),
-        soc_mean=jnp.zeros((n_chunks * n_int,), jnp.float32),
-        worst=jnp.zeros((), jnp.float32),
-        health_trace=jnp.zeros((n_chunks, 3), jnp.float32),
-        ess_frac=jnp.ones((n_chunks * n_int,), jnp.float32),
-        sm_trace=jnp.zeros((n_chunks, 6), jnp.float32),
-        obs=_observers_init(bank),
-    )
-    for c_idx, t0 in enumerate(range(0, t_total, chunk)):
-        # The trailing partial chunk runs at its natural length (one extra
-        # `step` compilation): `pdu.condition` ZOH-pads its trailing
-        # partial controller interval internally, exactly as a one-shot
-        # whole-trace call would, so the carried state / soc_mean /
-        # max_qp_residual never see whole pad intervals and stay
-        # chunk-size invariant (and scanned-engine identical).
-        n = min(chunk, t_total - t0)
-        tr = provider(t0, n)
-        if mesh is not None and not isinstance(tr, jax.Array):
-            tr = shard_racks(tr, mesh, rack_axis)  # host-resident input
-        if cfg.degraded_mode and faults is not None:
-            state, acc = step(
-                state, acc, tr, jnp.asarray(c_idx, jnp.int32), faults
-            )
-        elif cfg.degraded_mode:
-            if ess_online is None or ess_online.ndim < 2:
-                on = ess_online  # one mask (or None) for the whole stream
-            else:
-                on = ess_online[c_idx * n_int : c_idx * n_int + -(-n // k)]
-            # The hardware weight is per *sample*: it slices by samples.
-            wt = None if ess_weight is None else ess_weight[t0 : t0 + n]
-            state, acc = step(
-                state, acc, tr, jnp.asarray(c_idx, jnp.int32), on, wt
-            )
-        else:
-            state, acc = step(state, acc, tr, jnp.asarray(c_idx, jnp.int32))
-
-    return _finish_streaming(
-        cfg, grid_spec, state, acc.campus_rack, acc.campus_grid,
-        acc.soc_mean, acc.worst, bank, acc.obs, acc.health_trace,
-        acc.ess_frac, acc.sm_trace, t_total=t_total, n_ctrl=n_ctrl,
-    )
-
-
-def _condition_chunk(cfg, scen, st, t0, n, *, k, qp_iters, prep=None):
+def _condition_chunk(cfg, scen, st, t0, n, *, qp_iters, prep=None):
     """Render + condition one ``n``-sample chunk at absolute sample ``t0``.
 
-    The per-chunk building block shared by the scanned engine and the
-    grid-region engines (``core.grid``) — keeping it single-sourced is what
-    keeps the sharded region run bitwise against the sequential loop.  With
-    a fault schedule attached to the scenario (and a degraded-mode config)
-    the schedule itself is handed to ``pdu.condition`` together with the
+    The per-chunk building block of ``_scan_chunks``.  With a fault
+    schedule attached to the scenario (and a degraded-mode config) the
+    schedule itself is handed to ``pdu.condition`` together with the
     chunk's absolute start sample: availability is rendered *inside* the
     conditioning scan from the episode boundary tables (the degraded fast
     path; safe-mode configs fall back to the streamed derivation
@@ -666,32 +414,89 @@ def _condition_chunk(cfg, scen, st, t0, n, *, k, qp_iters, prep=None):
     )
 
 
+def _cat(xs):
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs)
+
+
+def _scan_chunks(cfg, scen, st, start, bank, *, chunk, n_full, rem, qp_iters,
+                 prep=None, poi=None):
+    """The chunk scan of one campus, traced inside the campus engine's jit
+    (``_scanned_engine``) and, per campus, the region engine's.
+
+    ``jax.lax.scan`` walks the ``n_full`` full chunks from absolute sample
+    ``start`` (a traced scalar): each renders and conditions its
+    ``(chunk, R)`` block (``_condition_chunk``) and folds the campus
+    compliance observers.  A ``rem``-sample ragged tail runs once more
+    after the scan at its *natural* length (``pdu.condition`` ZOH-pads the
+    trailing partial controller interval internally, exactly as a one-shot
+    whole-trace call would), so the state, ``soc_mean`` and the worst QP
+    residual never see pad intervals and are chunk-size invariant.
+
+    ``poi`` is a grid region's own per-chunk step, a pair ``(init, fold)``:
+    ``init()`` gives its extra scan carry, and ``fold(carry, ch)`` returns
+    the next carry and a tuple of the chunk's per-sample traces.  Returns
+    ``(state, CampusChunk, observers, poi carry, traces)``: the chunk's
+    fields joined over the window (health and safe-mode rows one per
+    chunk), and each ``fold`` trace as its list of full-scan and tail
+    pieces, for the caller to join.
+    """
+    init, fold = poi if poi is not None else (lambda: (), lambda c, ch: (c, ()))
+    obs = _observers_init(bank)
+    aux = init()
+
+    def step(st, obs, aux, t0, n):
+        st2, ch = _condition_chunk(
+            cfg, scen, st, t0, n, qp_iters=qp_iters, prep=prep)
+        obs2 = _observers_update(obs, bank, ch, cfg.sample_dt)
+        aux2, ys = fold(aux, ch)
+        return st2, obs2, aux2, ch, ys
+
+    parts, ys_parts, worst, htrace, strace = [], [], [], [], []
+    if n_full:
+        def body(carry, c_idx):
+            st2, obs2, aux2, ch, ys = step(*carry, start + c_idx * chunk, chunk)
+            return (st2, obs2, aux2), (ch, ys)
+
+        (st, obs, aux), (ch, ys) = jax.lax.scan(
+            body, (st, obs, aux), jnp.arange(n_full, dtype=jnp.int32))
+        parts.append(pdu.CampusChunk(
+            ch.campus_rack.reshape(-1), ch.campus_grid.reshape(-1),
+            ch.soc_mean.reshape(-1), None, None,
+            ch.ess_online_frac.reshape(-1),
+        ))
+        ys_parts.append(tuple(y.reshape(-1) for y in ys))
+        worst.append(jnp.max(ch.max_qp_residual))
+        htrace.append(ch.health)  # (n_full, 3)
+        strace.append(ch.safemode)  # (n_full, 6)
+    if rem:
+        st, obs, aux, ch, ys = step(st, obs, aux, start + n_full * chunk, rem)
+        parts.append(ch)
+        ys_parts.append(ys)
+        worst.append(ch.max_qp_residual)
+        htrace.append(ch.health[None])  # (1, 3)
+        strace.append(ch.safemode[None])  # (1, 6)
+    camp = pdu.CampusChunk(
+        campus_rack=_cat([p.campus_rack for p in parts]),
+        campus_grid=_cat([p.campus_grid for p in parts]),
+        soc_mean=_cat([p.soc_mean for p in parts]),
+        max_qp_residual=functools.reduce(jnp.maximum, worst),
+        health=_cat(htrace),
+        ess_online_frac=_cat([p.ess_online_frac for p in parts]),
+        safemode=_cat(strace),
+    )
+    return st, camp, obs, aux, [list(t) for t in zip(*ys_parts)]
+
+
 def _scanned_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, rack_axis, bank):
-    """Cached jitted scanned engine: the whole trace in ONE dispatch.
+    """Cached jitted scanned engine: the whole window in ONE dispatch.
 
-    ``jax.lax.scan`` walks the chunk index over the ``n_full`` full chunks;
-    each iteration renders its (chunk, R) block on-device
-    (``scenario.render`` with the traced chunk counter), optionally
-    constrains the rack sharding in-jit, runs ``pdu.condition_campus``,
-    and writes the campus aggregates into the scan's preallocated stacked
-    outputs.  A ``rem``-sample ragged tail is conditioned by an epilogue
-    step in the same jit at its *natural* length (static start index and
-    shape; ``pdu.condition`` ZOH-pads the trailing partial controller
-    interval internally, exactly as a one-shot whole-trace call would) —
-    so the returned state, ``soc_mean``, and ``max_qp_residual`` never see
-    pad intervals and are chunk-size invariant.  The scenario and the
-    start sample ride in as traced arguments, so one compilation serves
-    every scenario with the same structure and rack count — and every
-    resume point with the same remaining chunk geometry (e.g. fixed-size
-    windows of a long stream).
-
-    With a fault schedule attached to the scenario (and a degraded-mode
-    config), the per-interval ESS availability mask is derived *inside* the
-    jit from the schedule's episode table (``faults.interval_online`` is
-    pure in the absolute sample index, like the renderer), so the mask is
-    chunk- and resume-invariant by construction.  ``scen.faults is None``
-    vs a schedule changes the scenario treedef, which retraces the cached
-    jit automatically — no extra cache key needed.
+    One campus's ``_scan_chunks``, with the rack axis constrained to
+    ``mesh`` in-jit when one is given.  The scenario and the start sample
+    ride in as traced arguments, so one compilation serves every scenario
+    with the same structure and rack count — and every resume point with
+    the same remaining chunk geometry (e.g. fixed-size windows of a long
+    stream).  ``scen.faults is None`` vs a schedule changes the scenario
+    treedef, which retraces the cached jit automatically.
     """
     def prep(tr):
         if mesh is not None:
@@ -701,53 +506,10 @@ def _scanned_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, rack_axis, bank)
     def build():
         @functools.partial(jax.jit, donate_argnums=(1,))
         def run(scen, st, start):
-            obs = _observers_init(bank)
-
-            def body(carry, c_idx):
-                st, obs = carry
-                st2, ch = _condition_chunk(
-                    cfg, scen, st, start + c_idx * chunk, chunk,
-                    k=k, qp_iters=qp_iters, prep=prep,
-                )
-                obs2 = _observers_update(obs, bank, ch, cfg.sample_dt)
-                return (st2, obs2), ch
-
-            parts = []
-            worst = []
-            htrace = []
-            strace = []
-            if n_full:
-                (st, obs), ch = jax.lax.scan(
-                    body, (st, obs), jnp.arange(n_full, dtype=jnp.int32)
-                )
-                parts.append(pdu.CampusChunk(
-                    ch.campus_rack.reshape(-1), ch.campus_grid.reshape(-1),
-                    ch.soc_mean.reshape(-1), None, None,
-                    ch.ess_online_frac.reshape(-1),
-                ))
-                worst.append(jnp.max(ch.max_qp_residual))
-                htrace.append(ch.health)  # (n_full, 3)
-                strace.append(ch.safemode)  # (n_full, 6)
-            if rem:
-                st, ch = _condition_chunk(
-                    cfg, scen, st, start + n_full * chunk, rem,
-                    k=k, qp_iters=qp_iters, prep=prep,
-                )
-                obs = _observers_update(obs, bank, ch, cfg.sample_dt)
-                parts.append(ch)
-                worst.append(ch.max_qp_residual)
-                htrace.append(ch.health[None])  # (1, 3)
-                strace.append(ch.safemode[None])  # (1, 6)
-            cat = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs)
-            return st, pdu.CampusChunk(
-                campus_rack=cat([p.campus_rack for p in parts]),
-                campus_grid=cat([p.campus_grid for p in parts]),
-                soc_mean=cat([p.soc_mean for p in parts]),
-                max_qp_residual=functools.reduce(jnp.maximum, worst),
-                health=cat(htrace),
-                ess_online_frac=cat([p.ess_online_frac for p in parts]),
-                safemode=cat(strace),
-            ), obs
+            st, camp, obs, _, _ = _scan_chunks(
+                cfg, scen, st, start, bank, chunk=chunk, n_full=n_full,
+                rem=rem, qp_iters=qp_iters, prep=prep)
+            return st, camp, obs
 
         return run
 
@@ -758,7 +520,47 @@ def _scanned_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, rack_axis, bank)
     )
 
 
-def _condition_scenario_scanned_impl(
+def _chunk_geometry(cfg, target, chunk_intervals, start, stop):
+    """The streaming window ``[start, stop)`` of a scenario or region, cut
+    into ``chunk``-sample chunks of ``chunk_intervals`` controller
+    intervals (``k`` samples each): ``(k, chunk, start, stop, t_total,
+    n_full, rem, n_ctrl)``.  ``start`` must sit on an interval boundary so
+    a resumed state stays interval-aligned."""
+    k = max(int(round(float(cfg.controller.dt) / cfg.sample_dt)), 1)
+    chunk = max(int(chunk_intervals), 1) * k
+    total = target.total_samples
+    stop = total if stop is None else int(stop)
+    start = int(start)
+    if not 0 <= stop <= total:
+        raise ValueError(
+            f"stop_sample {stop} outside the scenario ({total} samples)")
+    if start < 0 or start % k:
+        raise ValueError(
+            f"start_sample {start} must be a non-negative multiple of the "
+            f"controller interval ({k} samples) so the resumed state stays "
+            "interval-aligned")
+    t_total = stop - start
+    if t_total <= 0:
+        raise ValueError(
+            f"start_sample {start} is past the scenario end "
+            f"(stop at {stop} samples)")
+    n_full, rem = divmod(t_total, chunk)
+    n_ctrl = -(-t_total // k)
+    return k, chunk, start, stop, t_total, n_full, rem, n_ctrl
+
+
+def _fresh_state(cfg, scen, start, soc0):
+    """The state a stream starts from at sample ``start`` of ``scen`` (an
+    unbatched scenario lifts to one rack, as the engines do)."""
+    from repro.power import scenario as SC
+
+    r0 = SC.render(scen, start, 1)[0]
+    if r0.ndim == 0:
+        r0 = r0[None]
+    return pdu.init_state(cfg, r0, soc0=soc0)
+
+
+def _condition_scanned_impl(
     cfg: pdu.PDUConfig,
     scenario,
     grid_spec: compliance.GridSpec,
@@ -774,17 +576,14 @@ def _condition_scenario_scanned_impl(
 ) -> ConditioningResult:
     """Device-resident streaming: render + condition in one scanned jit.
 
-    The host-loop engine pays per-chunk Python dispatch, a separately
-    jitted scenario render, and host-side accumulation.  Because
-    ``scenario.render(s, t0, n)`` is pure in the absolute sample index, the
-    render can move *inside* the step: a single ``jax.lax.scan`` over chunk
-    indices synthesizes each (chunk, R) block on-device, conditions it, and
-    stacks the campus aggregates into preallocated scan outputs — one
-    dispatch for the whole trace, zero host<->device ping-pong, donated
-    ``PDUState``, and rack sharding expressed as a
-    ``with_sharding_constraint`` inside the jit.  ``qp_iters`` / warm-start
-    semantics are bit-identical to the host-loop engine and to one-shot
-    ``condition_fleet`` at equal ``qp_iters``.
+    Because ``scenario.render(s, t0, n)`` is pure in the absolute sample
+    index, the render runs *inside* the engine: one ``jax.lax.scan`` over
+    chunk indices synthesizes each (chunk, R) block on-device, conditions
+    it, and stacks the campus aggregates — one dispatch for the whole
+    window, donated ``PDUState``, and rack sharding expressed as a
+    ``with_sharding_constraint`` inside the jit.  At equal ``qp_iters`` the
+    result matches the one-shot engine (the warm ADMM state rides across
+    chunks in ``PDUState.qp_warm``).
 
     ``state`` + ``start_sample`` / ``stop_sample`` window the stream: pass
     a previous call's returned state and the absolute sample index to
@@ -797,39 +596,12 @@ def _condition_scenario_scanned_impl(
     can seed several continuations.
     """
     with _prof.span("prepare"):
-        from repro.power import scenario as SC
-
         _check_scenario_rate(scenario, cfg)
         _check_scenario_faults(scenario, cfg)
-        k = max(int(round(float(cfg.controller.dt) / cfg.sample_dt)), 1)
-        chunk = max(int(chunk_intervals), 1) * k
-        start = int(start_sample)
-        stop = scenario.total_samples if stop_sample is None else int(stop_sample)
-        if not 0 <= stop <= scenario.total_samples:
-            raise ValueError(
-                f"stop_sample {stop} outside the scenario "
-                f"({scenario.total_samples} samples)"
-            )
-        if start < 0 or start % k:
-            raise ValueError(
-                f"start_sample {start} must be a non-negative multiple of the "
-                f"controller interval ({k} samples) so the resumed state stays "
-                "interval-aligned"
-            )
-        t_total = stop - start
-        if t_total <= 0:
-            raise ValueError(
-                f"start_sample {start} is past the scenario end "
-                f"(stop at {stop} samples)"
-            )
-        n_full, rem = divmod(t_total, chunk)
-        n_ctrl = -(-t_total // k)
-
+        k, chunk, start, stop, t_total, n_full, rem, n_ctrl = _chunk_geometry(
+            cfg, scenario, chunk_intervals, start_sample, stop_sample)
         if state is None:
-            r0 = SC.render(scenario, start, 1)[0]
-            if r0.ndim == 0:
-                r0 = r0[None]  # unbatched scenario: the engine lifts to 1 rack
-            state = pdu.init_state(cfg, r0, soc0=soc0)
+            state = _fresh_state(cfg, scenario, start, soc0)
         else:
             # The engine donates its state argument; copy so the caller's
             # checkpoint survives (and can seed several continuations).
@@ -867,10 +639,9 @@ def _check_scenario_faults(scenario, cfg: pdu.PDUConfig) -> None:
 
 
 def _scenario_fault_data(cfg: pdu.PDUConfig, scenario) -> dict:
-    """Precomputed availability mask/weight for engines that take them as
-    data (the one-shot engine, and the host loop when the caller overrides
-    one of the two inputs) — the same pure functions the fast path renders
-    from the episode tables, so every engine stays bitwise identical under
+    """Precomputed availability mask/weight for the one-shot engine, which
+    takes them as data — the same pure functions the scanned engine renders
+    from the episode tables, so both engines stay bitwise identical under
     any fault schedule."""
     if not (cfg.degraded_mode and getattr(scenario, "faults", None) is not None):
         return {}
@@ -886,54 +657,6 @@ def _scenario_fault_data(cfg: pdu.PDUConfig, scenario) -> dict:
     }
 
 
-def _condition_scenario_host_impl(
-    cfg: pdu.PDUConfig,
-    scenario,
-    grid_spec: compliance.GridSpec,
-    *,
-    mesh: jax.sharding.Mesh | None = None,
-    rack_axis: str = "data",
-    chunk_intervals: int = 16,
-    state: pdu.PDUState | None = None,
-    soc0: float = 0.5,
-    qp_iters: int = 30,
-    ess_online: jax.Array | None = None,
-    ess_weight: jax.Array | None = None,
-) -> ConditioningResult:
-    """Scenario via the per-chunk host loop — the slow oracle the scanned
-    engine is equivalence-tested against."""
-    from repro.power import scenario as SC
-
-    _check_scenario_rate(scenario, cfg)
-    _check_scenario_faults(scenario, cfg)
-    faulty = cfg.degraded_mode and getattr(scenario, "faults", None) is not None
-    if faulty and (ess_online is not None or ess_weight is not None):
-        # Caller-supplied overrides win; fill the missing half the legacy
-        # streamed way so overriding one input does not change the other.
-        fault_data = _scenario_fault_data(cfg, scenario)
-        if ess_online is None:
-            ess_online = fault_data.get("ess_online")
-        if ess_weight is None:
-            ess_weight = fault_data.get("ess_weight")
-        faulty = False
-    return _condition_fleet_streaming_impl(
-        cfg,
-        SC.chunk_provider(scenario),
-        grid_spec,
-        total_samples=scenario.total_samples,
-        soc0=soc0,
-        qp_iters=qp_iters,
-        chunk_intervals=chunk_intervals,
-        mesh=mesh,
-        rack_axis=rack_axis,
-        state=state,
-        ess_online=ess_online,
-        ess_weight=ess_weight,
-        faults=scenario.faults if faulty else None,
-        fault_edge=scenario.edge_width if faulty else 1,
-    )
-
-
 # ------------------------------------------------------------------- facade
 
 
@@ -944,16 +667,14 @@ class StreamOptions:
     ``chunk_intervals`` sizes the streaming chunk (controller intervals per
     chunk); ``state`` resumes a previous stream (a prior result's
     ``.state`` — a tuple of per-campus states for a grid region);
-    ``start_sample`` / ``stop_sample`` window the scanned engines over
-    ``[start, stop)`` of the unmodified scenario; ``total_samples`` is
-    required (and only meaningful) for raw chunk providers.
+    ``start_sample`` / ``stop_sample`` window the scanned engine over
+    ``[start, stop)`` of the unmodified scenario.
     """
 
     chunk_intervals: int = 16
     state: object = None
     start_sample: int = 0
     stop_sample: int | None = None
-    total_samples: int | None = None
 
 
 def _as_stream_options(stream) -> StreamOptions:
@@ -967,12 +688,20 @@ def _as_stream_options(stream) -> StreamOptions:
         f"stream must be a StreamOptions, dict or None, got {type(stream)!r}")
 
 
-def _reject_stream_options(so: StreamOptions, engine: str, *fields: str) -> None:
+def _reject_stream_options(so: StreamOptions) -> None:
+    """The one-shot engine conditions the whole trace from a fresh state."""
     defaults = StreamOptions()
-    for f in fields:
+    for f in ("state", "start_sample", "stop_sample"):
         if getattr(so, f) != getattr(defaults, f):
             raise ValueError(
-                f"stream option {f!r} is not supported by the {engine!r} engine")
+                f"stream option {f!r} is not supported by the 'oneshot' engine")
+
+
+def _unsupported(what: str) -> ValueError:
+    return ValueError(
+        f"{what}: the engines are 'scanned' (a Scenario or GridRegion) and "
+        "'oneshot' (a Scenario or a materialized (T, R) array); render a "
+        "Scenario, or pass the (T, R) trace array to engine='oneshot'")
 
 
 def condition(
@@ -986,7 +715,7 @@ def condition(
     stream: StreamOptions | dict | None = None,
     **kwargs,
 ) -> ConditioningResult:
-    """THE conditioning entry point: one facade over every engine.
+    """THE conditioning entry point: one facade over both engines.
 
     ``target`` selects the workload form:
 
@@ -996,24 +725,20 @@ def condition(
       interconnection (POI observers + mode-band verdicts ride along; with
       a ``mesh`` carrying a ``"campus"`` axis the campuses run in parallel
       under ``shard_map``, bitwise against the sequential loop);
-    * a materialized ``(T, R)`` rack-trace array, or a chunk provider
-      ``f(start, length) -> (length, R)`` (with ``stream.total_samples``).
+    * a materialized ``(T, R)`` rack-trace array (``engine="oneshot"``).
 
     ``engine`` picks the execution strategy: ``"scanned"`` (default —
-    render + condition in one scanned jit; scenarios/regions only),
-    ``"host"`` (per-chunk host loop, the slow oracle), or ``"oneshot"``
-    (whole-trace ``(T, R)`` materialization; supports ``use_plan=False``).
-    ``mesh`` is taken once here — rack sharding (``"data"`` axis) and
-    campus sharding (``"campus"`` axis) both derive from it.  ``stream``
-    bundles the windowing/resume options (see ``StreamOptions``).
-    Remaining keywords (``soc0``, ``qp_iters``, ``use_plan``,
-    ``ess_online``, ``ess_weight``) pass through to the engine.
+    render + condition in one scanned jit; scenarios/regions only) or
+    ``"oneshot"`` (whole-trace ``(T, R)`` materialization, the plain
+    reference; supports ``use_plan=False``).  ``mesh`` is taken once
+    here — rack sharding (``"data"`` axis) and campus sharding
+    (``"campus"`` axis) both derive from it.  ``stream`` bundles the
+    windowing/resume options (see ``StreamOptions``).  Remaining keywords
+    (``soc0``, ``qp_iters``, ``use_plan``, ``ess_online``, ``ess_weight``)
+    pass through to the engine.
 
     Returns a ``ConditioningResult`` whatever the path; fields the engine
-    does not track are ``None``.  The pre-facade entry points
-    (``condition_fleet``, ``condition_fleet_streaming``,
-    ``condition_scenario_scanned``, ``condition_scenario_streaming``)
-    remain as thin deprecated wrappers over this function.
+    does not track are ``None``.
     """
     with _prof.span("condition"):
         spec = compliance.GridSpec.create() if grid_spec is None else grid_spec
@@ -1025,7 +750,6 @@ def condition(
             if engine != "scanned":
                 raise ValueError(
                     f"grid regions run the scanned engine only (got {engine!r})")
-            _reject_stream_options(so, "grid-region", "total_samples")
             return _grid.condition_region(
                 cfg, target, spec, mesh=mesh,
                 chunk_intervals=so.chunk_intervals, states=so.state,
@@ -1033,178 +757,31 @@ def condition(
                 **kwargs,
             )
 
-        is_scenario = hasattr(target, "total_samples") and not callable(target)
-        if is_scenario:
-            if engine == "scanned":
-                _reject_stream_options(so, "scanned", "total_samples")
-                return _condition_scenario_scanned_impl(
-                    cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
-                    chunk_intervals=so.chunk_intervals, state=so.state,
-                    start_sample=so.start_sample, stop_sample=so.stop_sample,
-                    **kwargs,
-                )
-            if engine == "host":
-                _reject_stream_options(
-                    so, "host", "start_sample", "stop_sample", "total_samples")
-                return _condition_scenario_host_impl(
-                    cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
-                    chunk_intervals=so.chunk_intervals, state=so.state,
-                    **kwargs,
-                )
-            if engine == "oneshot":
-                from repro.power import scenario as SC
-
-                _reject_stream_options(
-                    so, "oneshot", "state", "start_sample", "stop_sample",
-                    "total_samples")
-                _check_scenario_rate(target, cfg)
-                _check_scenario_faults(target, cfg)
-                for key, val in _scenario_fault_data(cfg, target).items():
-                    kwargs.setdefault(key, val)
-                tr = SC.render(target, 0, target.total_samples)
-                if tr.ndim == 1:
-                    tr = tr[:, None]
-                return _condition_fleet_impl(cfg, tr, spec, **kwargs)
-            raise ValueError(
-                f"unknown engine {engine!r} "
-                "(expected 'scanned', 'host' or 'oneshot')")
-
-        # Raw (T, R) array or chunk provider.
-        if engine == "oneshot":
-            if callable(target):
-                raise ValueError(
-                    "engine='oneshot' needs a materialized (T, R) array "
-                    "(chunk providers stream via engine='host')")
-            _reject_stream_options(
-                so, "oneshot", "state", "start_sample", "stop_sample",
-                "total_samples")
-            return _condition_fleet_impl(cfg, target, spec, **kwargs)
-        if engine == "host":
-            _reject_stream_options(so, "host", "start_sample", "stop_sample")
-            return _condition_fleet_streaming_impl(
+        if engine not in ("scanned", "oneshot"):
+            raise _unsupported(f"unknown engine {engine!r}")
+        if callable(target):
+            raise _unsupported("a chunk provider is not a conditioning target")
+        is_scenario = hasattr(target, "total_samples")
+        if engine == "scanned":
+            if not is_scenario:
+                raise _unsupported(
+                    "engine='scanned' renders a Scenario or GridRegion in-jit")
+            return _condition_scanned_impl(
                 cfg, target, spec, mesh=mesh, rack_axis=rack_axis,
                 chunk_intervals=so.chunk_intervals, state=so.state,
-                total_samples=so.total_samples, **kwargs,
+                start_sample=so.start_sample, stop_sample=so.stop_sample,
+                **kwargs,
             )
-        if engine == "scanned":
-            raise ValueError(
-                "engine='scanned' renders a declarative Scenario/GridRegion "
-                "in-jit; raw trace arrays and chunk providers stream via "
-                "engine='host' (or engine='oneshot' for materialized arrays)")
-        raise ValueError(
-            f"unknown engine {engine!r} (expected 'scanned', 'host' or 'oneshot')")
 
+        _reject_stream_options(so)
+        if is_scenario:
+            from repro.power import scenario as SC
 
-# -------------------------------------------------- deprecated entry points
-# Thin wrappers over ``condition`` (golden-tested bitwise against it); kept
-# so seven PRs of call sites keep working.  Prefer the facade in new code.
-
-
-def condition_fleet(
-    cfg: pdu.PDUConfig,
-    traces: jax.Array,
-    grid_spec: compliance.GridSpec,
-    *,
-    soc0: float = 0.5,
-    qp_iters: int = 60,
-    use_plan: bool = True,
-    ess_online: jax.Array | None = None,
-    ess_weight: jax.Array | None = None,
-) -> ConditioningResult:
-    """One-shot whole-trace conditioning of a (T, R) rack-trace array.
-
-    .. deprecated:: prefer ``condition(traces, cfg, spec, engine="oneshot")``.
-    """
-    return condition(
-        traces, cfg, grid_spec, engine="oneshot", soc0=soc0,
-        qp_iters=qp_iters, use_plan=use_plan,
-        ess_online=ess_online, ess_weight=ess_weight,
-    )
-
-
-def condition_fleet_streaming(
-    cfg: pdu.PDUConfig,
-    traces: jax.Array | Callable[[int, int], jax.Array],
-    grid_spec: compliance.GridSpec,
-    *,
-    soc0: float = 0.5,
-    qp_iters: int = 30,
-    chunk_intervals: int = 16,
-    total_samples: int | None = None,
-    mesh: jax.sharding.Mesh | None = None,
-    rack_axis: str = "data",
-    state: pdu.PDUState | None = None,
-    ess_online: jax.Array | None = None,
-    ess_weight: jax.Array | None = None,
-) -> ConditioningResult:
-    """Host-loop streaming over a (T, R) array or chunk provider.
-
-    .. deprecated:: prefer ``condition(traces, cfg, spec, engine="host",
-       stream=StreamOptions(...))``.
-    """
-    return condition(
-        traces, cfg, grid_spec, engine="host", mesh=mesh, rack_axis=rack_axis,
-        stream=StreamOptions(chunk_intervals=chunk_intervals, state=state,
-                             total_samples=total_samples),
-        soc0=soc0, qp_iters=qp_iters,
-        ess_online=ess_online, ess_weight=ess_weight,
-    )
-
-
-def condition_scenario_scanned(
-    cfg: pdu.PDUConfig,
-    scenario,
-    grid_spec: compliance.GridSpec,
-    *,
-    soc0: float = 0.5,
-    qp_iters: int = 30,
-    chunk_intervals: int = 16,
-    mesh: jax.sharding.Mesh | None = None,
-    rack_axis: str = "data",
-    state: pdu.PDUState | None = None,
-    start_sample: int = 0,
-    stop_sample: int | None = None,
-) -> ConditioningResult:
-    """Scenario via the scanned engine (render + condition in one jit).
-
-    .. deprecated:: prefer ``condition(scenario, cfg, spec)`` — the facade
-       defaults to this engine.
-    """
-    return condition(
-        scenario, cfg, grid_spec, engine="scanned", mesh=mesh,
-        rack_axis=rack_axis,
-        stream=StreamOptions(chunk_intervals=chunk_intervals, state=state,
-                             start_sample=start_sample,
-                             stop_sample=stop_sample),
-        soc0=soc0, qp_iters=qp_iters,
-    )
-
-
-def condition_scenario_streaming(
-    cfg: pdu.PDUConfig,
-    scenario,
-    grid_spec: compliance.GridSpec,
-    *,
-    engine: str = "scanned",
-    **kwargs,
-) -> ConditioningResult:
-    """Condition a declarative ``repro.power.scenario.Scenario`` fleet.
-
-    .. deprecated:: prefer ``condition(scenario, cfg, spec,
-       engine="scanned"|"host")``.
-    """
-    if engine not in ("scanned", "host"):
-        raise ValueError(
-            f"unknown engine {engine!r} (expected 'scanned' or 'host')")
-    stream = StreamOptions(
-        chunk_intervals=kwargs.pop("chunk_intervals", 16),
-        state=kwargs.pop("state", None),
-        start_sample=kwargs.pop("start_sample", 0),
-        stop_sample=kwargs.pop("stop_sample", None),
-    )
-    return condition(
-        scenario, cfg, grid_spec, engine=engine,
-        mesh=kwargs.pop("mesh", None),
-        rack_axis=kwargs.pop("rack_axis", "data"),
-        stream=stream, **kwargs,
-    )
+            _check_scenario_rate(target, cfg)
+            _check_scenario_faults(target, cfg)
+            for key, val in _scenario_fault_data(cfg, target).items():
+                kwargs.setdefault(key, val)
+            target = SC.render(target, 0, target.total_samples)
+            if target.ndim == 1:
+                target = target[:, None]
+        return _condition_oneshot_impl(cfg, target, spec, **kwargs)

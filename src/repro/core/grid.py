@@ -434,26 +434,6 @@ def _poi_fold(bank, mbank, chunk, n_full, rem, dt):
 # -------------------------------------------------------------- engines
 
 
-def _chunk_geometry(cfg, region_or_scen, chunk_intervals, start, stop):
-    k = max(int(round(float(cfg.controller.dt) / cfg.sample_dt)), 1)
-    chunk = max(int(chunk_intervals), 1) * k
-    total = region_or_scen.total_samples
-    stop = total if stop is None else int(stop)
-    start = int(start)
-    if not 0 <= stop <= total:
-        raise ValueError(f"stop_sample {stop} outside the region ({total} samples)")
-    if start < 0 or start % k:
-        raise ValueError(
-            f"start_sample {start} must be a non-negative multiple of the "
-            f"controller interval ({k} samples)")
-    t_total = stop - start
-    if t_total <= 0:
-        raise ValueError(f"start_sample {start} is past the region end ({stop})")
-    n_full, rem = divmod(t_total, chunk)
-    n_ctrl = -(-t_total // k)
-    return k, chunk, start, stop, t_total, n_full, rem, n_ctrl
-
-
 def _assemble_region_result(
     cfg, reg, grid_spec, per, campus_rack, campus_grid, soc_mean,
     health_trace, ess_frac, max_qp, poi_rack, poi_grid, po, bank, mbank,
@@ -524,8 +504,9 @@ def condition_region_sequential(
     states = (None,) * C if states is None else tuple(states)
     if len(states) != C:
         raise ValueError(f"{len(states)} states for {C} campuses")
-    k, chunk, start, stop, t_total, n_full, rem, n_ctrl = _chunk_geometry(
+    geom = fleet._chunk_geometry(
         cfg, reg, chunk_intervals, start_sample, stop_sample)
+    k, chunk, start, stop, t_total, n_full, rem, n_ctrl = geom
     mesh1 = _oracle_mesh()
     one = jnp.ones((1,), jnp.float32)
     per = []
@@ -650,62 +631,22 @@ def _region_engine(cfg, qp_iters, chunk, k, n_full, rem, mesh, bank, mbank):
         def shard_body(scen_s, st_s, w_s, start):
             take0 = lambda t: jax.tree_util.tree_map(lambda x: x[0], t)
             scen, st, wl = take0(scen_s), take0(st_s), w_s[0]
-            obs = fleet._observers_init(bank)
-            po = _poi_observers_init(bank, mbank)
 
-            def fold(st, obs, po, t0, n):
-                st2, ch = fleet._condition_chunk(
-                    cfg, scen, st, t0, n, k=k, qp_iters=qp_iters)
-                obs2 = fleet._observers_update(obs, bank, ch, cfg.sample_dt)
+            def poi_fold(po, ch):
                 with _prof.scope("poi"):
                     pr = jax.lax.psum(wl * ch.campus_rack, caxis)
                     pg = jax.lax.psum(wl * ch.campus_grid, caxis)
                     po2 = _poi_observers_update(
                         po, bank, mbank, pr, pg, cfg.sample_dt)
-                return st2, obs2, po2, ch, pr, pg
+                return po2, (pr, pg)
 
-            parts, prs, pgs, worst, htrace, strace = [], [], [], [], [], []
-            if n_full:
-                def body(carry, c_idx):
-                    st, obs, po = carry
-                    st2, obs2, po2, ch, pr, pg = fold(
-                        st, obs, po, start + c_idx * chunk, chunk)
-                    return (st2, obs2, po2), (ch, pr, pg)
-
-                (st, obs, po), (ch, pr, pg) = jax.lax.scan(
-                    body, (st, obs, po),
-                    jnp.arange(n_full, dtype=jnp.int32))
-                parts.append(pdu.CampusChunk(
-                    ch.campus_rack.reshape(-1), ch.campus_grid.reshape(-1),
-                    ch.soc_mean.reshape(-1), None, None,
-                    ch.ess_online_frac.reshape(-1),
-                ))
-                prs.append(pr.reshape(-1))
-                pgs.append(pg.reshape(-1))
-                worst.append(jnp.max(ch.max_qp_residual))
-                htrace.append(ch.health)
-                strace.append(ch.safemode)
-            if rem:
-                st, obs, po, ch, pr, pg = fold(
-                    st, obs, po, start + n_full * chunk, rem)
-                parts.append(ch)
-                prs.append(pr)
-                pgs.append(pg)
-                worst.append(ch.max_qp_residual)
-                htrace.append(ch.health[None])
-                strace.append(ch.safemode[None])
-            cat = lambda xs: xs[0] if len(xs) == 1 else jnp.concatenate(xs)
-            camp = pdu.CampusChunk(
-                campus_rack=cat([p.campus_rack for p in parts]),
-                campus_grid=cat([p.campus_grid for p in parts]),
-                soc_mean=cat([p.soc_mean for p in parts]),
-                max_qp_residual=functools.reduce(jnp.maximum, worst),
-                health=cat(htrace),
-                ess_online_frac=cat([p.ess_online_frac for p in parts]),
-                safemode=cat(strace),
-            )
+            st, camp, obs, po, (prs, pgs) = fleet._scan_chunks(
+                cfg, scen, st, start, bank, chunk=chunk, n_full=n_full,
+                rem=rem, qp_iters=qp_iters,
+                poi=(lambda: _poi_observers_init(bank, mbank), poi_fold))
             lift = lambda t: jax.tree_util.tree_map(lambda x: x[None], t)
-            return lift(st), lift(camp), lift(obs), cat(prs), cat(pgs), po
+            return (lift(st), lift(camp), lift(obs), fleet._cat(prs),
+                    fleet._cat(pgs), po)
 
         f = rules.shard_map_compat(
             shard_body, mesh,
@@ -743,8 +684,6 @@ def condition_region_sharded(
     ``c``'s own results (``per_campus[c]``, ``state[c]``) live on the first
     device of its mesh row (``rules.campus_rows``), and a resume state may
     sit anywhere: each is moved to its campus's device once."""
-    from repro.power import scenario as SC
-
     C = reg.n_campuses
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     if "campus" not in axis_sizes:
@@ -761,8 +700,9 @@ def condition_region_sharded(
         for scen in reg.campuses:
             fleet._check_scenario_rate(scen, cfg)
             fleet._check_scenario_faults(scen, cfg)
-        k, chunk, start, stop, t_total, n_full, rem, n_ctrl = _chunk_geometry(
+        geom = fleet._chunk_geometry(
             cfg, reg, chunk_intervals, start_sample, stop_sample)
+        k, chunk, start, stop, t_total, n_full, rem, n_ctrl = geom
 
         states = (None,) * C if states is None else tuple(states)
         if len(states) != C:
@@ -773,13 +713,8 @@ def condition_region_sharded(
                     "per-campus resume states must be all-None (fresh start) "
                     "or all present")
 
-            def init_one(scen):
-                r0 = SC.render(scen, start, 1)[0]
-                if r0.ndim == 0:
-                    r0 = r0[None]
-                return pdu.init_state(cfg, r0, soc0=soc0)
-
-            states = tuple(init_one(scen) for scen in reg.campuses)
+            states = tuple(fleet._fresh_state(cfg, scen, start, soc0)
+                           for scen in reg.campuses)
         # Campus c's leaves stay on row c of the mesh, both ways.
         scen_s = _stack_campuses(reg, mesh)
         st_s = _stack_states(states, mesh)

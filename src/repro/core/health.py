@@ -40,7 +40,7 @@ Chunk-invariance contract: the cycle counter folds sample-by-sample inside
 a ``lax.scan`` whose carry is the state (bit-identical under ANY split of
 the SoC stream); the throughput/stress integrals fold one block reduction
 per ``update`` call — and every conditioning path calls ``update`` exactly
-once per controller interval, so scanned / host-loop / one-shot engines
+once per controller interval, so the scanned and one-shot engines
 (and resumed streams) produce bitwise-equal ``HealthState``s by
 construction.
 """
@@ -208,8 +208,8 @@ def update_consts(
     under ANY split of the stream; the reduction leaves (charge/discharge/
     SoC sums) are bit-identical under any split into the SAME blocks — and
     every conditioning path folds exactly one controller interval per
-    block, so scanned / host-loop / one-shot engines agree bitwise on the
-    whole state.
+    block, so the scanned and one-shot engines agree bitwise on the whole
+    state.
     """
     c0, c1, eps, kappa = consts
     prev_t = jnp.concatenate([state.prev_soc[None], soc[:-1]], axis=0)

@@ -814,11 +814,11 @@ def condition_campus(
     The per-rack grid waveform is reduced to campus means *inside* the same
     computation (XLA fuses the reduction into the conditioning scan), so a
     streaming engine that only needs campus-level compliance never
-    materializes the conditioned (T, R) block outside the step.  Shared by
-    the host-loop and scanned fleet engines so their per-chunk arithmetic
-    is identical by construction.  ``health`` is the fleet wear snapshot at
-    the chunk's end (zeros unless ``cfg.track_health``) — the online
-    telemetry a campus operator would chart.
+    materializes the conditioned (T, R) block outside the step.  It is the
+    per-chunk step of the fleet's chunk scan (``fleet._scan_chunks``), which
+    the campus and region engines share.  ``health`` is the fleet wear
+    snapshot at the chunk's end (zeros unless ``cfg.track_health``) — the
+    online telemetry a campus operator would chart.
     """
     grid, state2, telem = condition(
         cfg, state, rack_power, qp_iters=qp_iters, use_plan=use_plan,

@@ -18,9 +18,8 @@ construction with *data*: a scenario is a small struct-of-arrays IR —
 sample index: every output sample depends only on its own index (the edge
 smoothing is an explicit zero-padded window mean with a fixed reduction
 order), so chunked rendering is **bit-identical** to whole-trace rendering
-and the signature plugs directly into
-``fleet.condition_fleet_streaming``'s chunk provider — campus-scale traces
-are synthesized on-device per chunk and never materialized as (T, R).
+and the fleet's scanned engine renders each chunk on-device inside its
+scan — campus-scale traces are never materialized as (T, R).
 
 Workload parameters for the assigned model architectures are derived from
 their step cost (``workload_from_model`` / ``scenario_from_model``), and
@@ -570,8 +569,8 @@ render.__doc__ = """Render ``n`` samples starting at absolute sample ``t0``.
 
 Returns ``(n,)`` for an unbatched scenario or ``(n, R)`` for a per-rack
 batch.  Pure in the absolute index: ``render(s, 0, T)`` equals the
-concatenation of any chunking ``render(s, t0, n)`` bit-for-bit, so it
-serves directly as a streaming chunk provider (``chunk_provider``).
+concatenation of any chunking ``render(s, t0, n)`` bit-for-bit, so a
+stream can render chunk by chunk.
 """
 
 
@@ -580,8 +579,8 @@ def _render_padded_impl(s: Scenario, t0: jax.Array, n: int) -> jax.Array:
     t0 = jnp.asarray(t0, jnp.int32)
     idx = t0 + jnp.arange(n, dtype=jnp.int32)
     # Position of the last in-range sample within this chunk; holding it for
-    # every out-of-range row reproduces exactly the ZOH pad the host-loop
-    # engine applies to a ragged trailing chunk (repeat of tr[-1:]).
+    # every out-of-range row is a zero-order hold of the trace's last sample
+    # (a repeat of tr[-1:]).
     last = jnp.clip(jnp.int32(s.total_samples - 1) - t0, 0, n - 1)
     hold = jax.lax.dynamic_index_in_dim(tr, last, axis=0, keepdims=True)
     valid = idx < s.total_samples
@@ -620,17 +619,6 @@ def chunk_count(s: Scenario, chunk_samples: int) -> int:
 def render_trace(s: Scenario) -> tuple[jax.Array, float]:
     """Render the whole scenario; returns ``(trace, dt)`` like the legacy API."""
     return render(s, 0, s.total_samples), s.dt
-
-
-def chunk_provider(s: Scenario):
-    """A ``f(t0, n) -> (n, R)`` chunk provider for
-    ``fleet.condition_fleet_streaming`` — chunks are synthesized on-device,
-    never materialized as (T, R) on the host."""
-
-    def provider(t0: int, n: int) -> jax.Array:
-        return render(s, t0, int(n))
-
-    return provider
 
 
 # ------------------------------------------------- compiled phase timelines

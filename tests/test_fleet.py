@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.core import compliance, fleet, pdu
 from repro.power import trace
@@ -45,65 +44,10 @@ def test_fleet_conditioning_composes(tmp_path):
     traces = jnp.clip(traces, 0.0, 1.0)
     cfg = pdu.make_pdu(sample_dt=dt)
     spec = compliance.GridSpec.create()
-    res = fleet.condition_fleet(cfg, traces, spec, qp_iters=15)
+    res = fleet.condition(traces, cfg, spec, engine="oneshot", qp_iters=15)
     assert not bool(res.report_rack.ramp_ok)
     assert bool(res.report_grid.ramp_ok)
     assert bool(res.report_grid.ok)
-
-
-def test_streaming_fleet_matches_one_shot():
-    """condition_fleet_streaming (chunked, donated, campus-reduced) must
-    reproduce the one-shot vectorized call's campus waveform."""
-    sp = trace.TestbenchSpec(duration_s=44.0, sample_hz=200.0)
-    t1, dt = trace.testbench_trace(sp, jax.random.key(7))
-    traces = fleet.staggered_fleet(t1, 8, jax.random.key(8), max_offset_samples=800)
-    cfg = pdu.make_pdu(sample_dt=dt)
-    spec = compliance.GridSpec.create()
-    full = fleet.condition_fleet(cfg, traces, spec, qp_iters=30)
-    stream = fleet.condition_fleet_streaming(
-        cfg, traces, spec, qp_iters=30, chunk_intervals=3
-    )
-    np.testing.assert_allclose(
-        np.asarray(stream.campus_grid), np.asarray(full.campus_grid), atol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(stream.campus_rack), np.asarray(full.campus_rack), atol=1e-6
-    )
-    assert bool(stream.report_grid.ramp_ok)
-    assert float(stream.max_qp_residual) >= 0.0
-
-
-def test_streaming_fleet_chunk_provider():
-    """Hour-scale path: chunks synthesized on the fly (no (T, R) input array
-    ever materialized) produce the same campus result."""
-    sp = trace.TestbenchSpec(duration_s=44.0, sample_hz=200.0)
-    t1, dt = trace.testbench_trace(sp, jax.random.key(7))
-    traces = fleet.staggered_fleet(t1, 4, jax.random.key(9), max_offset_samples=400)
-    cfg = pdu.make_pdu(sample_dt=dt)
-    spec = compliance.GridSpec.create()
-    want = fleet.condition_fleet_streaming(
-        cfg, traces, spec, qp_iters=20, chunk_intervals=4
-    )
-    got = fleet.condition_fleet_streaming(
-        cfg,
-        lambda t0, n: traces[t0 : t0 + n],
-        spec,
-        qp_iters=20,
-        chunk_intervals=4,
-        total_samples=traces.shape[0],
-    )
-    np.testing.assert_allclose(
-        np.asarray(got.campus_grid), np.asarray(want.campus_grid), atol=1e-6
-    )
-
-
-def test_streaming_fleet_requires_total_samples_with_provider():
-    cfg = pdu.make_pdu(sample_dt=5e-3)
-    spec = compliance.GridSpec.create()
-    with pytest.raises(ValueError, match="total_samples"):
-        fleet.condition_fleet_streaming(
-            cfg, lambda t0, n: jnp.zeros((n, 2)), spec
-        )
 
 
 def test_rack_failure_mid_trace():
@@ -116,7 +60,7 @@ def test_rack_failure_mid_trace():
     traces = fleet.apply_failures(traces, fails, p_idle=0.02)
     cfg = pdu.make_pdu(sample_dt=dt)
     spec = compliance.GridSpec.create()
-    res = fleet.condition_fleet(cfg, traces, spec, qp_iters=15)
+    res = fleet.condition(traces, cfg, spec, engine="oneshot", qp_iters=15)
     assert bool(res.report_grid.ramp_ok)
     # the failed rack's own conditioned trace tapers instead of stepping:
     failed_grid = np.asarray(res.grid_traces[:, 1])

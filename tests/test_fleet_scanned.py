@@ -1,10 +1,11 @@
-"""Engine-equivalence tests: scanned vs host-loop vs one-shot (ISSUE 4).
+"""Engine-equivalence tests: the scanned engine vs the one-shot reference.
 
-The scanned engine (`fleet.condition_scenario_scanned`) fuses on-device
+The scanned engine (`fleet.condition(scenario, ...)`) fuses on-device
 chunk rendering and the chunk loop into one `lax.scan`-ned jit; the
-host-loop engine walks the same chunks from Python; `condition_fleet` is
-the one-shot whole-trace oracle.  All three share `pdu.condition_campus`,
-so their per-chunk arithmetic is identical by construction.
+one-shot engine (`engine="oneshot"`, or `pdu.condition` on the rendered
+trace) conditions the whole trace in one call and is the reference.  A
+one-campus grid region runs the same chunk scan (`fleet._scan_chunks`)
+and must equal the campus engine bit for bit.
 
 Tolerance contract: XLA CPU contracts mul+add chains into FMAs differently
 depending on the fusion context, so quantities that pass through the LC
@@ -44,6 +45,14 @@ def _cfg():
     return pdu.make_pdu(sample_dt=1.0 / _HZ)
 
 
+def _scanned(cfg, s, *, chunk_intervals=16, state=None, start_sample=0,
+             stop_sample=None, **kw):
+    return fleet.condition(
+        s, cfg, _SPEC, stream=fleet.StreamOptions(
+            chunk_intervals=chunk_intervals, state=state,
+            start_sample=start_sample, stop_sample=stop_sample), **kw)
+
+
 def _assert_results_match(a, b, *, grid_atol=_ULP):
     np.testing.assert_array_equal(np.asarray(a.campus_rack), np.asarray(b.campus_rack))
     np.testing.assert_array_equal(np.asarray(a.soc_mean), np.asarray(b.soc_mean))
@@ -60,31 +69,15 @@ def _assert_states_match(sa, sb, *, atol=_ULP):
         np.testing.assert_allclose(np.asarray(la), np.asarray(lb), atol=atol)
 
 
-def test_scanned_matches_host_loop():
-    """Same scenario, same chunking: the one-dispatch scanned engine must
-    reproduce the per-chunk host loop — including the final PDUState, so
-    either engine's stream can be resumed by the other."""
-    s = _campus()
-    cfg = _cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=20, chunk_intervals=2)
-    b = fleet.condition_scenario_streaming(
-        cfg, s, _SPEC, engine="host", qp_iters=20, chunk_intervals=2
-    )
-    assert a.campus_grid.shape == (s.total_samples,)
-    _assert_results_match(a, b)
-    _assert_states_match(a.state, b.state)
-    assert bool(a.report_grid.ramp_ok)
-
-
 @pytest.mark.slow
 def test_scanned_matches_one_shot_condition_fleet():
     """Chunked-with-carried-warm-state == one whole-trace call at equal
     qp_iters (the PR-1 streaming contract, now via the scanned engine)."""
     s = _campus()
     cfg = _cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=20, chunk_intervals=2)
+    a = _scanned(cfg, s, qp_iters=20, chunk_intervals=2)
     full = SC.render(s, 0, s.total_samples)
-    res = fleet.condition_fleet(cfg, full, _SPEC, qp_iters=20)
+    res = fleet.condition(full, cfg, _SPEC, engine="oneshot", qp_iters=20)
     np.testing.assert_array_equal(np.asarray(a.campus_rack), np.asarray(res.campus_rack))
     np.testing.assert_allclose(
         np.asarray(a.campus_grid), np.asarray(res.campus_grid), atol=1e-5
@@ -95,18 +88,38 @@ def test_scanned_matches_one_shot_condition_fleet():
     _assert_states_match(a.state, st_f, atol=1e-5)
 
 
+def _one_shot_reference(cfg, s, qp_iters):
+    """The whole trace through ``pdu.condition`` in one call, reduced to
+    the campus aggregates the engines return, and its final state."""
+    full = SC.render(s, 0, s.total_samples)
+    grid, st_f, telem = pdu.condition(
+        cfg, pdu.init_state(cfg, full[0]), full, qp_iters=qp_iters)
+    return fleet.ConditioningResult(
+        campus_rack=jnp.mean(full, axis=1),
+        campus_grid=jnp.mean(grid, axis=1),
+        soc_mean=jnp.mean(telem.soc, axis=1),
+        max_qp_residual=jnp.max(telem.qp_residual),
+        state=st_f,
+    )
+
+
 @pytest.mark.slow
-@pytest.mark.parametrize("duration_s", [32.5, 37.3])
-def test_scanned_ragged_final_chunk(duration_s):
-    """The epilogue step's static-index ZOH pad must reproduce the host
-    loop's explicit pad — including a tail shorter than one controller
-    interval (32.5 s: 500-sample tail against k = 1000)."""
+@pytest.mark.parametrize("duration_s, chunk_intervals", [
+    pytest.param(32.5, 2, id="32.5"),
+    pytest.param(37.3, 2, id="37.3"),
+    pytest.param(37.3, 3, id="37.3-chunk3"),
+    pytest.param(46.5, 3, id="46.5-chunk3"),
+])
+def test_scanned_ragged_final_chunk(duration_s, chunk_intervals):
+    """The epilogue step conditions the ragged tail at its natural length,
+    so the chunked run reproduces the one-shot whole-trace call —
+    including a tail shorter than one controller interval (32.5 s at two
+    intervals a chunk and 46.5 s at three: 500- and 300-sample tails
+    against k = 1000)."""
     s = _campus(n_racks=4, duration_s=duration_s)
     cfg = _cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=15, chunk_intervals=2)
-    b = fleet.condition_scenario_streaming(
-        cfg, s, _SPEC, engine="host", qp_iters=15, chunk_intervals=2
-    )
+    a = _scanned(cfg, s, qp_iters=15, chunk_intervals=chunk_intervals)
+    b = _one_shot_reference(cfg, s, qp_iters=15)
     k = int(round(float(cfg.controller.dt) * _HZ))
     assert a.campus_grid.shape == (s.total_samples,)
     assert a.soc_mean.shape == (-(-s.total_samples // k),)
@@ -120,8 +133,8 @@ def test_scanned_chunk_intervals_invariance():
     the chunk size must not change the result."""
     s = _campus(n_racks=4)
     cfg = _cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=15, chunk_intervals=2)
-    b = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=15, chunk_intervals=4)
+    a = _scanned(cfg, s, qp_iters=15, chunk_intervals=2)
+    b = _scanned(cfg, s, qp_iters=15, chunk_intervals=4)
     _assert_results_match(a, b)
     _assert_states_match(a.state, b.state)
 
@@ -131,14 +144,14 @@ def test_scanned_resume_from_returned_state():
     returned state must reproduce the unsplit run."""
     s = _campus(n_racks=4)
     cfg = _cfg()
-    full = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=15, chunk_intervals=2)
+    full = _scanned(cfg, s, qp_iters=15, chunk_intervals=2)
     k = int(round(float(cfg.controller.dt) * _HZ))
     t_cut = 2 * 2 * k  # two chunks
-    first = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=15, chunk_intervals=2, stop_sample=t_cut
+    first = _scanned(
+        cfg, s, qp_iters=15, chunk_intervals=2, stop_sample=t_cut
     )
-    rest = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=15, chunk_intervals=2,
+    rest = _scanned(
+        cfg, s, qp_iters=15, chunk_intervals=2,
         state=first.state, start_sample=t_cut,
     )
     assert rest.campus_grid.shape == (s.total_samples - t_cut,)
@@ -154,8 +167,8 @@ def test_scanned_resume_from_returned_state():
 def test_scanned_resume_past_end_raises():
     s = _campus(n_racks=2, duration_s=20.0)
     with pytest.raises(ValueError, match="past the scenario end"):
-        fleet.condition_scenario_scanned(
-            _cfg(), s, _SPEC, start_sample=s.total_samples
+        _scanned(
+            _cfg(), s, start_sample=s.total_samples
         )
 
 
@@ -164,7 +177,7 @@ def test_scanned_start_sample_must_be_interval_aligned():
     cfg = _cfg()
     for bad in (-1000, 137):  # negative, and not a multiple of k=1000
         with pytest.raises(ValueError, match="multiple of the controller interval"):
-            fleet.condition_scenario_scanned(cfg, s, _SPEC, start_sample=bad)
+            _scanned(cfg, s, start_sample=bad)
 
 
 def test_resume_state_is_not_consumed():
@@ -173,29 +186,23 @@ def test_resume_state_is_not_consumed():
     s = _campus(n_racks=2, duration_s=30.0)
     cfg = _cfg()
     k = int(round(float(cfg.controller.dt) * _HZ))
-    first = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=10, chunk_intervals=2, stop_sample=2 * k
+    first = _scanned(
+        cfg, s, qp_iters=10, chunk_intervals=2, stop_sample=2 * k
     )
-    a = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=10, chunk_intervals=2,
+    a = _scanned(
+        cfg, s, qp_iters=10, chunk_intervals=2,
         state=first.state, start_sample=2 * k,
     )
-    b = fleet.condition_scenario_scanned(  # same checkpoint, second use
-        cfg, s, _SPEC, qp_iters=10, chunk_intervals=2,
+    b = _scanned(  # same checkpoint, second use
+        cfg, s, qp_iters=10, chunk_intervals=2,
         state=first.state, start_sample=2 * k,
     )
     np.testing.assert_array_equal(np.asarray(a.campus_grid), np.asarray(b.campus_grid))
-    # host-loop path: same contract
-    tr = SC.render(s, 0, s.total_samples)
-    h1 = fleet.condition_fleet_streaming(cfg, tr[: 2 * k], _SPEC, qp_iters=10)
-    h2 = fleet.condition_fleet_streaming(cfg, tr[2 * k :], _SPEC, qp_iters=10, state=h1.state)
-    h3 = fleet.condition_fleet_streaming(cfg, tr[2 * k :], _SPEC, qp_iters=10, state=h1.state)
-    np.testing.assert_array_equal(np.asarray(h2.campus_grid), np.asarray(h3.campus_grid))
 
 
 def test_scanned_unbatched_scenario_lifts_to_one_rack():
     s = SC.scenario_from_model("llama3_2_1b", duration_s=20.0, sample_hz=_HZ)
-    res = fleet.condition_scenario_scanned(_cfg(), s, _SPEC, qp_iters=10)
+    res = _scanned(_cfg(), s, qp_iters=10)
     assert res.campus_grid.shape == (s.total_samples,)
     assert np.all(np.isfinite(np.asarray(res.campus_grid)))
 
@@ -203,7 +210,7 @@ def test_scanned_unbatched_scenario_lifts_to_one_rack():
 def test_condition_scenario_streaming_rejects_unknown_engine():
     s = _campus(n_racks=2, duration_s=20.0)
     with pytest.raises(ValueError, match="unknown engine"):
-        fleet.condition_scenario_streaming(_cfg(), s, _SPEC, engine="warp")
+        fleet.condition(s, _cfg(), _SPEC, engine="warp")
 
 
 def test_condition_campus_is_the_reduced_condition():
@@ -271,10 +278,10 @@ def test_shard_racks_in_jit_single_device_is_noop():
     s = _campus(n_racks=4, duration_s=20.0)
     cfg = _cfg()
     mesh = make_mesh((1,), ("data",))
-    a = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=10, chunk_intervals=2, mesh=mesh
+    a = _scanned(
+        cfg, s, qp_iters=10, chunk_intervals=2, mesh=mesh
     )
-    b = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=10, chunk_intervals=2)
+    b = _scanned(cfg, s, qp_iters=10, chunk_intervals=2)
     np.testing.assert_array_equal(np.asarray(a.campus_rack), np.asarray(b.campus_rack))
     np.testing.assert_allclose(
         np.asarray(a.campus_grid), np.asarray(b.campus_grid), atol=_ULP
@@ -303,37 +310,30 @@ def _deg_cfg():
 def test_faulty_scenario_requires_degraded_mode():
     s = _faulty_campus()
     with pytest.raises(ValueError):
-        fleet.condition_scenario_scanned(_cfg(), s, _SPEC)
+        _scanned(_cfg(), s)
     with pytest.raises(ValueError):
-        fleet.condition_scenario_streaming(_cfg(), s, _SPEC, engine="host")
+        fleet.condition(s, _cfg(), _SPEC, engine="oneshot")
 
 
 @pytest.mark.slow
 def test_degraded_engines_match_under_stochastic_schedule():
-    """scanned == host == one-shot under a stochastic fault schedule, to
-    the repo's standing tolerance contract (rack/soc/mask aggregates
-    bitwise; filter-chain outputs within FMA-contraction slack)."""
+    """scanned == one-shot under a stochastic fault schedule, to the
+    repo's standing tolerance contract (rack/mask aggregates bitwise;
+    filter-chain outputs within FMA-contraction slack)."""
     from repro.power import faults as FLT
 
     s = _faulty_campus()
     cfg = _deg_cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=20, chunk_intervals=2)
-    b = fleet.condition_scenario_streaming(
-        cfg, s, _SPEC, engine="host", qp_iters=20, chunk_intervals=2
-    )
-    _assert_results_match(a, b)
-    np.testing.assert_array_equal(
-        np.asarray(a.ess_online_frac), np.asarray(b.ess_online_frac)
-    )
-    _assert_states_match(a.state, b.state)
+    a = _scanned(cfg, s, qp_iters=20, chunk_intervals=2)
 
     k = int(round(float(cfg.controller.dt) * _HZ))
     n_ctrl = -(-s.total_samples // k)
     on = FLT.interval_online(s.faults, 0, n_ctrl, k)
     wt = FLT.ess_weight(s.faults, 0, s.total_samples, s.edge_width)
     tr = SC.render(s, 0, s.total_samples)
-    res = fleet.condition_fleet(
-        cfg, tr, _SPEC, qp_iters=20, ess_online=on, ess_weight=wt
+    res = fleet.condition(
+        tr, cfg, _SPEC, engine="oneshot", qp_iters=20, ess_online=on,
+        ess_weight=wt,
     )
     np.testing.assert_array_equal(
         np.asarray(a.campus_rack), np.asarray(res.campus_rack)
@@ -364,8 +364,8 @@ def test_degraded_fault_on_chunk_boundary():
     )
     s = SC.attach_faults(s, sched)
     cfg = _deg_cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=15, chunk_intervals=2)
-    b = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=15, chunk_intervals=4)
+    a = _scanned(cfg, s, qp_iters=15, chunk_intervals=2)
+    b = _scanned(cfg, s, qp_iters=15, chunk_intervals=4)
     _assert_results_match(a, b)
     np.testing.assert_array_equal(
         np.asarray(a.ess_online_frac), np.asarray(b.ess_online_frac)
@@ -386,13 +386,13 @@ def test_degraded_resume_mid_outage():
     s = _faulty_campus()
     cfg = _deg_cfg()
     k = int(round(float(cfg.controller.dt) * _HZ))
-    full = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=20, chunk_intervals=2)
+    full = _scanned(cfg, s, qp_iters=20, chunk_intervals=2)
     cut = 4 * k  # resume point: interval-aligned, inside the fault soup
-    a = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=20, chunk_intervals=2, stop_sample=cut
+    a = _scanned(
+        cfg, s, qp_iters=20, chunk_intervals=2, stop_sample=cut
     )
-    b = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=20, chunk_intervals=2,
+    b = _scanned(
+        cfg, s, qp_iters=20, chunk_intervals=2,
         state=a.state, start_sample=cut,
     )
     np.testing.assert_array_equal(
@@ -419,7 +419,7 @@ def test_fleet_summary_json_safe_round_trip():
     s = _campus(n_racks=3, duration_s=20.0)
     cfg = _cfg()  # track_health off -> empty history -> inf lifetime
     tr = SC.render(s, 0, s.total_samples)
-    res = fleet.condition_fleet(cfg, tr, _SPEC, qp_iters=10)
+    res = fleet.condition(tr, cfg, _SPEC, engine="oneshot", qp_iters=10)
     raw = hlt.fleet_summary(res.health)
     assert raw["projected_life_years_min"] == float("inf")
     with pytest.raises(ValueError):
@@ -427,3 +427,37 @@ def test_fleet_summary_json_safe_round_trip():
     safe = hlt.fleet_summary(res.health, json_safe=True)
     assert safe["projected_life_years_min"] is None
     assert json.loads(json.dumps(safe, allow_nan=False)) == safe
+
+
+# ------------------------------------- one chunk scan for campus and region
+
+
+def _one_campus_region(s):
+    from repro.core import grid
+
+    return grid.region([s], salt_noise=False)
+
+
+@pytest.mark.parametrize("case", ["44.0", "37.3", "health", "degraded"])
+def test_one_campus_region_is_the_campus_engine(case):
+    """A one-campus grid region runs the campus engine's chunk scan: its
+    campus aggregates and final state equal the scanned campus engine's
+    bit for bit."""
+    if case == "degraded":
+        s, cfg = _faulty_campus(), _deg_cfg()
+    else:
+        s = _campus(n_racks=4, duration_s=37.3 if case == "37.3" else 44.0)
+        cfg = pdu.make_pdu(sample_dt=1.0 / _HZ, track_health=case == "health")
+    a = _scanned(cfg, s, qp_iters=15, chunk_intervals=2)
+    r = fleet.condition(_one_campus_region(s), cfg, _SPEC, qp_iters=15,
+                        stream=dict(chunk_intervals=2))
+    for f in ("campus_rack", "campus_grid", "soc_mean", "ess_online_frac",
+              "health_trace", "safemode_trace"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(r, f))[0], np.asarray(getattr(a, f)), err_msg=f)
+    np.testing.assert_array_equal(
+        np.asarray(r.max_qp_residual), np.asarray(a.max_qp_residual))
+    (st,) = r.state
+    for la, lb in zip(jax.tree_util.tree_leaves(st),
+                      jax.tree_util.tree_leaves(a.state)):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))

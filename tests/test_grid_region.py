@@ -1,5 +1,5 @@
 """Grid-region tests (ISSUE 8): POI aggregation, swing coupling, mode-band
-verdicts, the ``fleet.condition`` facade vs its deprecated wrappers, and
+verdicts, the ``fleet.condition`` facade's target forms and engines, and
 campus sharding.
 
 Bitwise contract: the sequential region engine routes every campus through
@@ -212,7 +212,7 @@ def test_synchronized_checkpoints_ring_staggered_cancel():
     assert float(np.max(np.abs(np.asarray(rs.poi_freq_dev)))) < 1.0
 
 
-# ------------------------------------------------- facade vs legacy wrappers
+# ------------------------------------------------------------------ facade
 
 
 def _assert_bitwise(a, b):
@@ -225,46 +225,21 @@ def _assert_bitwise(a, b):
         _tree_equal(va, vb)
 
 
-def test_facade_matches_condition_fleet_oneshot():
+def test_oneshot_on_array_matches_oneshot_on_scenario():
+    """The one-shot engine on a Scenario is the same call as on the (T, R)
+    array that Scenario renders."""
     cfg = _cfg()
-    traces = SC.render(_campus(), 0, 1500)
-    legacy = fleet.condition_fleet(cfg, traces, _SPEC)
-    new = fleet.condition(traces, cfg, _SPEC, engine="oneshot")
-    _assert_bitwise(legacy, new)
-    assert legacy.campus_grid is not None
-
-
-def test_facade_matches_condition_fleet_streaming():
-    cfg = _cfg()
-    traces = SC.render(_campus(), 0, 1500)
-    legacy = fleet.condition_fleet_streaming(cfg, traces, _SPEC,
-                                             chunk_intervals=2)
-    new = fleet.condition(traces, cfg, _SPEC, engine="host",
-                          stream=dict(chunk_intervals=2))
-    _assert_bitwise(legacy, new)
-
-
-def test_facade_matches_condition_scenario_scanned():
-    cfg = _cfg()
-    scen = _campus()
-    legacy = fleet.condition_scenario_scanned(cfg, scen, _SPEC)
-    new = fleet.condition(scen, cfg, _SPEC)
-    _assert_bitwise(legacy, new)
-
-
-def test_facade_matches_condition_scenario_streaming_host():
-    cfg = _cfg()
-    scen = _campus()
-    legacy = fleet.condition_scenario_streaming(cfg, scen, _SPEC,
-                                                engine="host")
-    new = fleet.condition(scen, cfg, _SPEC, engine="host")
-    _assert_bitwise(legacy, new)
+    scen = _campus(duration_s=30.0)
+    from_scen = fleet.condition(scen, cfg, _SPEC, engine="oneshot")
+    traces = SC.render(scen, 0, scen.total_samples)
+    from_array = fleet.condition(traces, cfg, _SPEC, engine="oneshot")
+    _assert_bitwise(from_scen, from_array)
+    assert from_array.grid_traces.shape == traces.shape
 
 
 def test_result_aliases_and_report():
-    assert fleet.FleetResult is fleet.ConditioningResult
-    assert fleet.StreamingFleetResult is fleet.ConditioningResult
     res = fleet.condition(_campus(), _cfg(), _SPEC)
+    assert isinstance(res, fleet.ConditioningResult)
     rep = res.report("grid")
     assert bool(np.asarray(rep.ramp_ok)) == bool(
         np.asarray(res.report_grid.ramp_ok))
@@ -272,13 +247,34 @@ def test_result_aliases_and_report():
         res.report("nope")
 
 
+@pytest.mark.parametrize("target, engine", [
+    pytest.param("scenario", "host", id="host-scenario"),
+    pytest.param("array", "host", id="host-array"),
+    pytest.param("callable", "scanned", id="callable"),
+])
+def test_facade_rejects_removed_engine_and_target_forms(target, engine):
+    """The host loop and chunk providers are gone: the facade names the
+    two engines left and how to reach them."""
+    scen = _campus(duration_s=20.0)
+    tr = SC.render(scen, 0, scen.total_samples)
+    target = {"scenario": scen, "array": tr,
+              "callable": lambda t0, n: tr[t0:t0 + n]}[target]
+    with pytest.raises(ValueError, match="'scanned'.*'oneshot'") as e:
+        fleet.condition(target, _cfg(), _SPEC, engine=engine)
+    assert "render a Scenario" in str(e.value)
+    assert "(T, R)" in str(e.value)
+
+
 def test_facade_rejects_bad_engines_and_stream_options():
     cfg = _cfg()
     reg = _small_region(duration_s=20.0)
     with pytest.raises(ValueError, match="scanned engine only"):
-        fleet.condition(reg, cfg, _SPEC, engine="host")
-    with pytest.raises(ValueError, match="total_samples"):
+        fleet.condition(reg, cfg, _SPEC, engine="oneshot")
+    with pytest.raises(TypeError, match="total_samples"):
         fleet.condition(reg, cfg, _SPEC, stream=dict(total_samples=100))
+    with pytest.raises(ValueError, match="not supported by the 'oneshot'"):
+        fleet.condition(_campus(), cfg, _SPEC, engine="oneshot",
+                        stream=dict(start_sample=0, stop_sample=500))
     with pytest.raises(ValueError, match="unknown engine"):
         fleet.condition(SC.render(_campus(), 0, 500), cfg, _SPEC,
                         engine="warp")

@@ -7,7 +7,7 @@ Three contracts pinned here:
     (rainflow-equivalent) reference on synthetic traces, and the whole
     ``HealthState`` is bit-identical under any chunking of the SoC stream
     — through raw ``health.update`` folds, chunked ``pdu.condition``
-    calls, and all three fleet engines (incl. ragged tails and resume).
+    calls, and both fleet engines (incl. ragged tails and resume).
   * The streaming compliance observers reproduce the whole-trace oracles:
     the cross-chunk ramp observer equals ``max_abs_ramp`` bit-for-bit
     (including a worst-case step placed exactly on a chunk boundary — the
@@ -215,6 +215,14 @@ def _cfg(**kw):
     return pdu.make_pdu(sample_dt=1.0 / _HZ, **kw)
 
 
+def _scanned(cfg, s, *, chunk_intervals=16, state=None, start_sample=0,
+             stop_sample=None, **kw):
+    return fleet.condition(
+        s, cfg, _SPEC, stream=fleet.StreamOptions(
+            chunk_intervals=chunk_intervals, state=state,
+            start_sample=start_sample, stop_sample=stop_sample), **kw)
+
+
 def _assert_health_equal(ha, hb, what=""):
     for name, a, b in zip(ha._fields, ha, hb):
         assert np.array_equal(np.asarray(a), np.asarray(b)), f"{what}{name}"
@@ -236,22 +244,12 @@ def test_condition_chunked_equals_one_shot_health():
 
 @pytest.mark.parametrize("duration_s", [44.0, 32.5])
 def test_engines_agree_on_health(duration_s):
-    """scanned == host-loop == one-shot for every health accumulator,
-    bitwise — including a ragged tail shorter than one controller
-    interval (32.5 s against k = 1000 chunks)."""
+    """scanned == one-shot for every health accumulator, bitwise —
+    including a ragged tail shorter than one controller interval (32.5 s
+    against k = 1000 chunks)."""
     s = _campus(4, duration_s)
     cfg = _cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=10, chunk_intervals=2)
-    b = fleet.condition_scenario_streaming(
-        cfg, s, _SPEC, engine="host", qp_iters=10, chunk_intervals=2
-    )
-    _assert_health_equal(a.state.health, b.state.health, "scanned vs host: ")
-    # per-chunk telemetry: EFC / max-DoD columns are raw accumulators
-    # (bitwise); the fade column is a derived mul+add chain, which XLA
-    # FMA-contracts differently per fusion context (few-ulp contract).
-    ta, tb = np.asarray(a.health_trace), np.asarray(b.health_trace)
-    np.testing.assert_array_equal(ta[:, [0, 2]], tb[:, [0, 2]])
-    np.testing.assert_allclose(ta[:, 1], tb[:, 1], rtol=1e-5, atol=1e-9)
+    a = _scanned(cfg, s, qp_iters=10, chunk_intervals=2)
     full = SC.render(s, 0, s.total_samples)
     st0 = pdu.init_state(cfg, full[0])
     _, st_f, _ = pdu.condition(cfg, st0, full, qp_iters=10)
@@ -259,7 +257,8 @@ def test_engines_agree_on_health(duration_s):
     # derived fade agrees too (pure function of bitwise-equal accumulators)
     np.testing.assert_allclose(
         np.asarray(a.health.capacity_fade),
-        np.asarray(b.health.capacity_fade),
+        np.asarray(H.report(cfg.health, cfg.ess_params, st_f.health,
+                            cfg.sample_dt).capacity_fade),
         atol=1e-5,
     )
 
@@ -267,15 +266,15 @@ def test_engines_agree_on_health(duration_s):
 def test_health_is_chunk_size_invariant_and_resume_safe():
     s = _campus(4)
     cfg = _cfg()
-    a = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=10, chunk_intervals=2)
-    b = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=10, chunk_intervals=4)
+    a = _scanned(cfg, s, qp_iters=10, chunk_intervals=2)
+    b = _scanned(cfg, s, qp_iters=10, chunk_intervals=4)
     _assert_health_equal(a.state.health, b.state.health, "chunk size: ")
     k = int(round(float(cfg.controller.dt) * _HZ))
-    first = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=10, chunk_intervals=2, stop_sample=4 * k
+    first = _scanned(
+        cfg, s, qp_iters=10, chunk_intervals=2, stop_sample=4 * k
     )
-    rest = fleet.condition_scenario_scanned(
-        cfg, s, _SPEC, qp_iters=10, chunk_intervals=2,
+    rest = _scanned(
+        cfg, s, qp_iters=10, chunk_intervals=2,
         state=first.state, start_sample=4 * k,
     )
     _assert_health_equal(a.state.health, rest.state.health, "resume: ")
@@ -329,15 +328,15 @@ def test_megakernel_fold_matches_hybrid_update_bitwise():
 
 def test_health_trace_monotone_and_disabled_is_zero():
     s = _campus(3)
-    res = fleet.condition_scenario_scanned(_cfg(), s, _SPEC, qp_iters=10, chunk_intervals=2)
+    res = _scanned(_cfg(), s, qp_iters=10, chunk_intervals=2)
     ht = np.asarray(res.health_trace)
     assert ht.shape[1] == 3
     # accumulators only grow chunk over chunk
     assert np.all(np.diff(ht[:, 0]) >= 0)  # mean EFC
     assert np.all(np.diff(ht[:, 1]) >= 0)  # max fade
     assert float(ht[-1, 0]) > 0
-    off = fleet.condition_scenario_scanned(
-        pdu.make_pdu(sample_dt=1.0 / _HZ), s, _SPEC, qp_iters=10, chunk_intervals=2
+    off = _scanned(
+        pdu.make_pdu(sample_dt=1.0 / _HZ), s, qp_iters=10, chunk_intervals=2
     )
     assert np.all(np.asarray(off.health_trace) == 0.0)
     assert float(np.max(np.asarray(off.health.capacity_fade))) == 0.0
@@ -418,11 +417,16 @@ def test_streaming_engine_sees_boundary_step():
     cfg = pdu.make_pdu(sample_dt=1.0 / _HZ)
     k = int(round(float(cfg.controller.dt) * _HZ))
     chunk = 2 * k  # chunk_intervals=2
-    tr = np.full((2 * chunk, 2), 0.3, np.float32)
-    tr[chunk:] = 0.9  # step exactly at the chunk boundary
-    res = fleet.condition_fleet_streaming(
-        cfg, jnp.asarray(tr), _SPEC, qp_iters=5, chunk_intervals=2
-    )
+    # two racks at 0.3 pu for one chunk, then 0.9: the step sits exactly
+    # at the chunk boundary (no edge smoothing, no noise)
+    s = SC.from_phase_timeline(
+        [chunk / _HZ, chunk / _HZ], [[0.3, 0.9], [0.3, 0.9]], _HZ,
+        edge_time_s=0.0)
+    tr = np.asarray(SC.render(s, 0, s.total_samples))
+    assert s.total_samples == 2 * chunk
+    assert np.all(tr[:chunk] == np.float32(0.3))
+    assert np.all(tr[chunk:] == np.float32(0.9))
+    res = _scanned(cfg, s, qp_iters=5, chunk_intervals=2)
     expected = float(compliance.max_abs_ramp(jnp.mean(jnp.asarray(tr), axis=1), 1.0 / _HZ))
     assert float(res.report_rack.max_ramp) == pytest.approx(expected)
     assert not bool(res.report_rack.ramp_ok)
@@ -472,7 +476,7 @@ def test_streaming_report_matches_whole_trace_compliance():
     ramp exactly, spectral lines <= 1e-5."""
     s = _campus(4)
     cfg = _cfg()
-    res = fleet.condition_scenario_scanned(cfg, s, _SPEC, qp_iters=10, chunk_intervals=2)
+    res = _scanned(cfg, s, qp_iters=10, chunk_intervals=2)
     camp = np.asarray(res.campus_grid)
     assert float(res.report_grid.max_ramp) == float(
         compliance.max_abs_ramp(jnp.asarray(camp), 1.0 / _HZ)

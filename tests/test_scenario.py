@@ -231,8 +231,8 @@ def test_mixed_campus_structure():
 
 def test_mixed_campus_streams_end_to_end():
     """Acceptance path (scaled down): heterogeneous campus with staggered
-    starts + fault cascade conditions through condition_fleet_streaming via
-    the on-device scenario chunk provider and comes out grid-compliant."""
+    starts + fault cascade conditions through the scanned engine (chunks
+    rendered on-device) and comes out grid-compliant."""
     hz = 200.0
     s = SC.mixed_campus(
         8,
@@ -246,7 +246,7 @@ def test_mixed_campus_streams_end_to_end():
     )
     cfg = pdu.make_pdu(sample_dt=1.0 / hz)
     spec = compliance.GridSpec.create()
-    res = fleet.condition_scenario_streaming(cfg, s, spec, qp_iters=20, chunk_intervals=4)
+    res = fleet.condition(s, cfg, spec, qp_iters=20, stream=dict(chunk_intervals=4))
     assert res.campus_grid.shape == (s.total_samples,)
     assert not bool(res.report_rack.ramp_ok)  # raw campus violates beta
     assert bool(res.report_grid.ramp_ok)  # conditioned campus complies
@@ -257,38 +257,7 @@ def test_condition_scenario_streaming_checks_sample_rate():
     s = SC.scenario_from_model("llama3_2_1b", duration_s=10.0, sample_hz=100.0)
     cfg = pdu.make_pdu(sample_dt=1e-3)
     with pytest.raises(ValueError, match="sample rate"):
-        fleet.condition_scenario_streaming(cfg, s, compliance.GridSpec.create())
-
-
-# ------------------------------------------------ streaming ragged-chunk fix
-
-
-@pytest.mark.parametrize("duration_s", [32.5, 37.3])
-def test_streaming_ragged_final_chunk_matches_one_shot(duration_s):
-    """ZOH-padding the trailing partial chunk (so `step` compiles once) must
-    not change the campus waveform — including when the tail is shorter
-    than one controller interval (32.5 s case: final chunk is 500 samples
-    against k = 1000)."""
-    hz = 200.0
-    sp = trace.TestbenchSpec(duration_s=duration_s, sample_hz=hz)
-    t1, dt = trace.testbench_trace(sp, jax.random.key(5))
-    traces = fleet.staggered_fleet(t1, 4, jax.random.key(6), max_offset_samples=300)
-    cfg = pdu.make_pdu(sample_dt=dt)
-    spec = compliance.GridSpec.create()
-    full = fleet.condition_fleet(cfg, traces, spec, qp_iters=20)
-    stream = fleet.condition_fleet_streaming(
-        cfg, traces, spec, qp_iters=20, chunk_intervals=3
-    )
-    t_total = traces.shape[0]
-    assert stream.campus_grid.shape == (t_total,)
-    k = int(round(float(cfg.controller.dt) / dt))
-    assert stream.soc_mean.shape == (-(-t_total // k),)
-    np.testing.assert_allclose(
-        np.asarray(stream.campus_grid), np.asarray(full.campus_grid), atol=1e-5
-    )
-    np.testing.assert_allclose(
-        np.asarray(stream.campus_rack), np.asarray(full.campus_rack), atol=1e-6
-    )
+        fleet.condition(s, cfg, compliance.GridSpec.create())
 
 
 # ------------------------------------------------------- PowerSim satellites
