@@ -344,20 +344,26 @@ def spectrum_observer_update(
     )
 
 
+def hann_norm(n: int) -> jax.Array:
+    """``n`` times the mean of the length-``n`` Hann window: the
+    coherent-gain normalization of a Hann bank's line magnitudes."""
+    w = 0.5 - 0.5 * jnp.cos(2.0 * jnp.pi * jnp.arange(n) / n)
+    return n * jnp.mean(w)
+
+
 def spectrum_observer_finalize(
-    bank: SpectrumBank, obs: SpectrumObserver
+    bank: SpectrumBank, obs: SpectrumObserver, norm: jax.Array | None = None
 ) -> tuple[np.ndarray, jax.Array]:
     """(freqs [Hz], S) at the bank lines, normalized exactly like
     ``normalized_spectrum`` (coherent-gain corrected, one-sided doubling).
-    Hann banks normalize by the configured total length; rectangular
-    (online) banks by the samples seen so far."""
+    Hann banks normalize by the configured total length (``norm``, when
+    given, is its ``hann_norm`` computed beforehand); rectangular (online)
+    banks by the samples seen so far."""
     if not bank.bins:
         return np.zeros((0,)), jnp.zeros((0,), jnp.float32)
     mag = jnp.sqrt(obs.acc_re**2 + obs.acc_im**2)
     if bank.window == "hann":
-        n = bank.modulus
-        w = 0.5 - 0.5 * jnp.cos(2.0 * jnp.pi * jnp.arange(n) / n)
-        norm = n * jnp.mean(w)
+        norm = hann_norm(bank.modulus) if norm is None else norm
     else:
         norm = jnp.maximum(obs.n.astype(jnp.float32), 1.0)
     bins = np.asarray(bank.bins, np.int64)
@@ -373,11 +379,15 @@ def report_from_observers(
     ramp: RampObserver,
     bank: SpectrumBank,
     sob: SpectrumObserver,
+    norm: jax.Array | None = None,
 ) -> ComplianceReport:
     """ComplianceReport from streaming state: the ramp bound is exact; the
     spectral bound is evaluated at the bank's monitored lines (all >= f_c
-    by construction) rather than every DFT bin."""
-    _, s = spectrum_observer_finalize(bank, sob)
+    by construction) rather than every DFT bin.  ``norm`` is a Hann bank's
+    ``hann_norm``, computed beforehand by a caller that compiles the
+    report (traced inside a program, the window's ``cos`` and mean may be
+    folded at compile time to another ulp)."""
+    _, s = spectrum_observer_finalize(bank, sob, norm)
     worst = jnp.max(s, initial=0.0)
     ramp_ok = ramp.max_ramp <= spec.beta
     spectrum_ok = worst <= spec.alpha

@@ -143,11 +143,11 @@ class ConditioningResult(NamedTuple):
         if self.observers is None or self.bank is None or self.grid_spec is None:
             return pre
         key = "grid" if which == "poi" else which
-        rep = compliance.report_from_observers(
+        rep = _report_program(self.bank)(
             self.grid_spec,
             getattr(self.observers, f"ramp_{key}"),
-            self.bank,
             getattr(self.observers, f"spec_{key}"),
+            _spectrum_norm(self.bank),
         )
         if pre is not None and pre.mode_mags is not None:
             rep = compliance.with_mode_verdicts(rep, pre.mode_mags, pre.mode_ok)
@@ -340,6 +340,69 @@ def make_condition_step(cfg: pdu.PDUConfig, *, qp_iters: int = 30, donate: bool 
     return _cached_engine(_engine_key(cfg, "condition_step", qp_iters, donate), build)
 
 
+def _spectrum_norm(bank: compliance.SpectrumBank):
+    """A Hann bank's ``compliance.hann_norm`` as a host scalar, computed op
+    by op as an eager report computes it, once per bank length: traced
+    into a program, the window's ``cos`` and mean fold at compile time to
+    another ulp.  ``None`` where the report needs none (no lines, or a bank
+    that normalizes by the samples seen)."""
+    if bank.window != "hann" or not bank.bins:
+        return None
+
+    def build():
+        with jax.ensure_compile_time_eval():
+            return np.asarray(compliance.hann_norm(bank.modulus))
+
+    return _cached_engine(("spectrum_norm", bank.modulus), build)
+
+
+def _report_program(bank: compliance.SpectrumBank):
+    """``compliance.report_from_observers`` for one stream, as one cached
+    program ``(spec, ramp, spectrum observer, norm) -> report``: what
+    ``ConditioningResult.report`` runs, and what the finish traces twice."""
+    def build():
+        return jax.jit(
+            lambda spec, ramp, sob, norm: compliance.report_from_observers(
+                spec, ramp, bank, sob, norm))
+
+    return _cached_engine(("report", bank), build)
+
+
+def _finish_program(cfg, bank, t_total, n_ctrl):
+    """The finish's device work as one cached program ``(spec, norm,
+    observers, health params, ESS params, health state, aggregates) ->
+    (cut aggregates, rack report, grid report, wear report)``: the window
+    cuts of the engine's padded outputs, both compliance reports and the
+    wear report.  It runs where its committed inputs are (a region's
+    campus on its own chip).  The wear and ESS parameters come in as
+    arguments: closed over, they would be constants, and XLA turns a
+    division by a constant into a multiply.  Against the eager reports
+    only XLA's fused multiply-adds move a few fields, by a rounding each
+    (``tests/test_fleet_finish.py``)."""
+    report = _report_program(bank)
+
+    def build():
+        @jax.jit
+        def finish(spec, norm, obs, hp, ep, health, campus_rack, campus_grid,
+                   soc_mean, ess_frac):
+            if t_total is not None:
+                campus_rack, campus_grid = (
+                    campus_rack[:t_total], campus_grid[:t_total])
+            if n_ctrl is not None:
+                soc_mean, ess_frac = soc_mean[:n_ctrl], ess_frac[:n_ctrl]
+            return (
+                campus_rack, campus_grid, soc_mean, ess_frac,
+                report(spec, obs.ramp_rack, obs.spec_rack, norm),
+                report(spec, obs.ramp_grid, obs.spec_grid, norm),
+                hlt.report(hp, ep, health, cfg.sample_dt),
+            )
+
+        return finish
+
+    return _cached_engine(
+        _engine_key(cfg, "finish", bank, t_total, n_ctrl), build)
+
+
 def _finish_streaming(
     cfg, grid_spec, state, campus_rack, campus_grid, soc_mean, worst,
     bank, obs, health_trace, ess_frac=None, sm_trace=None, *,
@@ -351,28 +414,25 @@ def _finish_streaming(
     returned for plotting/diagnostics but no longer gate compliance.  The
     observers (and their bank/spec) ride along so ``.report()`` can
     re-derive reports later.  ``t_total`` / ``n_ctrl`` cut the engine's
-    padded sample / interval outputs to the stream's length."""
+    padded sample / interval outputs to the stream's length.  The device
+    work is one program (``_finish_program``)."""
     with _prof.span("finish"):
-        if t_total is not None:
-            campus_rack, campus_grid = campus_rack[:t_total], campus_grid[:t_total]
-        if n_ctrl is not None:
-            soc_mean, ess_frac = soc_mean[:n_ctrl], ess_frac[:n_ctrl]
+        finish = _finish_program(cfg, bank, t_total, n_ctrl)
+        (campus_rack, campus_grid, soc_mean, ess_frac, report_rack,
+         report_grid, health) = finish(
+            grid_spec, _spectrum_norm(bank), obs, _health_params(cfg),
+            cfg.ess_params, state.health, campus_rack, campus_grid, soc_mean,
+            ess_frac)
         return ConditioningResult(
             campus_rack=campus_rack,
             campus_grid=campus_grid,
             soc_mean=soc_mean,
-            report_rack=compliance.report_from_observers(
-                grid_spec, obs.ramp_rack, bank, obs.spec_rack
-            ),
-            report_grid=compliance.report_from_observers(
-                grid_spec, obs.ramp_grid, bank, obs.spec_grid
-            ),
+            report_rack=report_rack,
+            report_grid=report_grid,
             state=state,
             max_qp_residual=worst,
             health_trace=health_trace,
-            health=hlt.report(
-                _health_params(cfg), cfg.ess_params, state.health, cfg.sample_dt
-            ),
+            health=health,
             ess_online_frac=ess_frac,
             safemode_trace=sm_trace,
             grid_spec=grid_spec,
@@ -605,7 +665,7 @@ def _condition_scanned_impl(
         else:
             # The engine donates its state argument; copy so the caller's
             # checkpoint survives (and can seed several continuations).
-            state = jax.tree_util.tree_map(jnp.copy, state)
+            state = _copy_tree(state)
 
         bank = _make_bank(grid_spec, cfg, t_total)
         run = _scanned_engine(
@@ -618,6 +678,12 @@ def _condition_scanned_impl(
         ch.soc_mean, ch.max_qp_residual, bank, obs, ch.health,
         ch.ess_online_frac, ch.safemode, t_total=t_total, n_ctrl=n_ctrl,
     )
+
+
+@jax.jit
+def _copy_tree(tree):
+    """A tree's leaves copied into fresh buffers, in one program."""
+    return jax.tree_util.tree_map(jnp.copy, tree)
 
 
 def _check_scenario_rate(scenario, cfg: pdu.PDUConfig) -> None:

@@ -240,9 +240,15 @@ def test_oneshot_on_array_matches_oneshot_on_scenario():
 def test_result_aliases_and_report():
     res = fleet.condition(_campus(), _cfg(), _SPEC)
     assert isinstance(res, fleet.ConditioningResult)
-    rep = res.report("grid")
-    assert bool(np.asarray(rep.ramp_ok)) == bool(
-        np.asarray(res.report_grid.ramp_ok))
+    # ``.report()`` runs the program the finish traced: every field equal.
+    for which in ("rack", "grid"):
+        rep, stored = res.report(which), getattr(res, f"report_{which}")
+        for f in rep._fields:
+            a, b = getattr(rep, f), getattr(stored, f)
+            assert (a is None) == (b is None), f
+            if a is not None:
+                assert np.asarray(a).dtype == np.asarray(b).dtype, f
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b), f)
     with pytest.raises(ValueError):
         res.report("nope")
 
